@@ -285,7 +285,7 @@ class NoJitInHotpath(Rule):
     def _is_jit_call(self, call: ast.Call) -> bool:
         func = call.func
         if isinstance(func, ast.Name):
-            return func.id in self.JIT_NAMES or func.id == "_shard_map"
+            return func.id in self.JIT_NAMES
         dotted = _dotted(func)
         return _last_attr(dotted) in self.JIT_NAMES
 

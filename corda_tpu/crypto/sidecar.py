@@ -87,7 +87,7 @@ from typing import Sequence
 import numpy as np
 
 from ..obs import telemetry as _tm
-from .provider import VerifyJob, make_verifier
+from .provider import VerifyJob, exit_on_warm_failure, make_verifier
 
 OP_VERIFY = 1
 OP_STATS = 2
@@ -363,6 +363,8 @@ class SidecarServer:
         self.sigs = 0
         self.cross_request_batches = 0
         self.errors = 0
+        # Keyed by each dispatch's bucket: an oversized request dispatches
+        # in max_sigs slices, each counted on its own.
         self.batch_sigs_hist: dict[int, int] = {}
         self.wait_s_total = 0.0
         self.verify_s_total = 0.0
@@ -432,7 +434,10 @@ class SidecarServer:
     def _warm_maybe(self) -> None:
         """Same boot-warm contract as node._warm_verifier_maybe: install a
         closed device_gate, compile in the background, open the gate when
-        the device answers. Host traffic flows (host-routed) meanwhile."""
+        the device answers. Host traffic flows (host-routed) meanwhile. A
+        warm-up that fails on an accelerator ends the process
+        (provider.exit_on_warm_failure) — the sidecar exists to own the
+        device, so it never serves its whole life from the host tier."""
         verifier = self.verifier
         if not getattr(verifier, "name", "").startswith("jax"):
             return
@@ -445,30 +450,32 @@ class SidecarServer:
         is_mesh = hasattr(type(verifier), "mesh")
 
         def _warm() -> None:
-            ok = False
+            backend = None
             try:
+                import jax
+
+                backend = jax.default_backend()
                 if is_mesh:
                     # The mesh must be PROVEN before the gate opens:
                     # make_mesh raises when fewer local devices exist than
                     # asked for, and an open gate would route every batch
                     # into that raise.
                     self.mesh_devices = int(verifier.mesh.devices.size)
-                import jax
-
-                if jax.default_backend() != "cpu":
+                if backend != "cpu":
                     verifier.warm()
                 # else: CPU-backend compiles are cheap; no warm needed
-                ok = True
             except Exception as exc:
                 self.warm_error = f"{type(exc).__name__}: {exc}"
-            if ok or not is_mesh:
-                # Non-mesh verifiers keep the PR-5 contract: the gate opens
-                # even after a failed warm, the first failing dispatch
-                # produces an error REPLY, and the client degrades. A mesh
-                # that could not be built must never open the gate — every
-                # batch host-routes to the oracle-exact tier instead of
-                # raising per batch (degraded throughput, right answers).
-                gate.set()
+                if backend != "cpu":
+                    exit_on_warm_failure(f"sidecar {self.address}")
+                # CPU backend (tests, virtual meshes): a non-mesh verifier
+                # opens the gate and the first failing dispatch produces an
+                # error REPLY; a mesh that could not be built keeps the gate
+                # closed, so every batch takes the oracle-exact host tier.
+                if not is_mesh:
+                    gate.set()
+                return
+            gate.set()
 
         threading.Thread(target=_warm, daemon=True,
                          name="sidecar-warm").start()
@@ -588,16 +595,20 @@ class SidecarServer:
         return dl
 
     def _form_batch(self) -> tuple[list[_Pending], bool]:
-        """Take up to max_sigs from pending. With no bulk requests waiting
-        this is exactly the old FIFO popleft loop (bit-identical order);
-        when both classes wait, interactive (and unlabelled) requests pack
-        first — FIFO within each class — so a full batch is cut from the
-        latency-sensitive end and bulk rides the next one. Returns (batch,
-        any bulk was deferred behind interactive). Called under _cv."""
+        """Take up to max_sigs from pending, whole requests only: a batch
+        never grows past max_sigs (the bucket the warm-up compiled) unless
+        one request alone is larger. With no bulk requests waiting this is
+        a FIFO popleft loop; when both classes wait, interactive (and
+        unlabelled) requests pack first — FIFO within each class — so a
+        full batch is cut from the latency-sensitive end and bulk rides the
+        next one. Returns (batch, any bulk was deferred behind
+        interactive). Called under _cv."""
         if not any(p.lane == LANE_CODE_BULK for p in self._pending):
             batch: list[_Pending] = []
             total = 0
-            while self._pending and total < self.max_sigs:
+            while self._pending and (
+                    not batch
+                    or total + len(self._pending[0].jobs) <= self.max_sigs):
                 p = self._pending.popleft()
                 batch.append(p)
                 total += len(p.jobs)
@@ -607,7 +618,7 @@ class SidecarServer:
                    + [p for p in pending if p.lane == LANE_CODE_BULK])
         batch, taken, total = [], set(), 0
         for p in ordered:
-            if total >= self.max_sigs:
+            if batch and total + len(p.jobs) > self.max_sigs:
                 break
             batch.append(p)
             taken.add(id(p))
@@ -674,7 +685,7 @@ class SidecarServer:
             packed = None
             pack_s = 0.0
             pack_fn = getattr(self.verifier, "pack_device", None)
-            if pack_fn is not None:
+            if pack_fn is not None and len(jobs) <= self.max_sigs:
                 t_pack = time.perf_counter()
                 try:
                     packed = pack_fn(jobs)
@@ -691,24 +702,16 @@ class SidecarServer:
             if item is _STOP:
                 return
             batch, jobs, packed, pack_s = item
-            before_dev = getattr(self.verifier, "device_batches", 0) or 0
             t0 = time.perf_counter()
             err = None
             try:
-                if packed is not None:
-                    # Pre-packed by the scheduler (overlapped with the
-                    # previous batch's device execution): dispatch only.
-                    ok = self.verifier.verify_packed(packed)
-                else:
-                    ok = self.verifier.verify_batch(jobs)
+                ok, tier = self._dispatch(jobs, packed)
             except Exception as exc:  # noqa: BLE001
                 # Providers reject-never-raise, but a dying device backend
                 # can still throw; an error REPLY (not silence) lets the
                 # client degrade immediately instead of eating a deadline.
-                ok, err = None, exc
+                ok, tier, err = None, 0, exc
             verify_s = time.perf_counter() - t0
-            tier = 1 if (getattr(self.verifier, "device_batches", 0)
-                         or 0) > before_dev else 0
             if _tm.ACTIVE is not None:
                 _tm.inc("sidecar_batches_total")
                 _tm.inc("sidecar_sigs_total", len(jobs))
@@ -720,28 +723,11 @@ class SidecarServer:
                     self.cross_request_batches += 1
                 if err is not None:
                     self.errors += 1
-                b = bucket_for(len(jobs))
-                self.batch_sigs_hist[b] = self.batch_sigs_hist.get(b, 0) + 1
                 self.verify_s_total += verify_s
                 self.wait_s_total += sum(t0 - p.received_at for p in batch)
                 if packed is not None:
                     self.packed_batches += 1
                     self.pack_s_total += pack_s
-                if tier == 1 and err is None:
-                    # Pad attribution: the packed handle knows the exact
-                    # dispatched bucket and mesh width; the unsplit device
-                    # path is reconstructed arithmetically (same ladder).
-                    ndev = (packed.n_devices if packed is not None
-                            else (self.mesh_devices or self.devices or 1))
-                    lanes = (packed.bucket if packed is not None
-                             else pad_to_devices(bucket_for(len(jobs)), ndev))
-                    real = (len(packed.good) if packed is not None
-                            else len(jobs))
-                    self.device_lanes += lanes
-                    self.pad_lanes += lanes - real
-                    share = lanes // ndev
-                    self.per_device_batch_sigs_hist[share] = (
-                        self.per_device_batch_sigs_hist.get(share, 0) + 1)
                 if self.adaptive_coalesce:
                     self._adapt_observe(len(batch), len(jobs))
             offset = 0
@@ -762,6 +748,54 @@ class SidecarServer:
                 except OSError:
                     pass  # client died mid-batch: its flows replay
             self._slots.release()
+
+    def _device_batches(self) -> int:
+        return getattr(self.verifier, "device_batches", 0) or 0
+
+    def _dispatch(self, jobs, packed) -> tuple[np.ndarray, int]:
+        """Verify one formed batch; returns (verdicts, tier), tier 1 when
+        any of it ran on the device. A pre-packed batch dispatches as
+        packed. Otherwise verify_batch runs in slices of at most max_sigs:
+        one request larger than a bucket must not dispatch a bucket the
+        warm-up never compiled (a cold compile there outlasts every client
+        deadline). Each dispatch is recorded with its own size and tier."""
+        if packed is not None:
+            before = self._device_batches()
+            ok = self.verifier.verify_packed(packed)
+            tier = int(self._device_batches() > before)
+            self._record_dispatch(len(jobs), tier, packed)
+            return ok, tier
+        parts, tier = [], 0
+        for i in range(0, max(len(jobs), 1), self.max_sigs):
+            part = jobs[i:i + self.max_sigs]
+            before = self._device_batches()
+            parts.append(self.verifier.verify_batch(part))
+            on_device = int(self._device_batches() > before)
+            self._record_dispatch(len(part), on_device, None)
+            tier |= on_device
+        ok = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        return ok, tier
+
+    def _record_dispatch(self, n: int, tier: int, packed) -> None:
+        """One dispatch of n signatures: its bucket and, on the device
+        tier, its lanes. The packed handle knows the exact dispatched
+        bucket and mesh width; the unsplit device path is reconstructed
+        arithmetically (same ladder)."""
+        b = bucket_for(n)
+        with self._lock:
+            self.batch_sigs_hist[b] = self.batch_sigs_hist.get(b, 0) + 1
+            if not tier:
+                return
+            ndev = (packed.n_devices if packed is not None
+                    else (self.mesh_devices or self.devices or 1))
+            lanes = (packed.bucket if packed is not None
+                     else pad_to_devices(b, ndev))
+            real = len(packed.good) if packed is not None else n
+            self.device_lanes += lanes
+            self.pad_lanes += lanes - real
+            share = lanes // ndev
+            self.per_device_batch_sigs_hist[share] = (
+                self.per_device_batch_sigs_hist.get(share, 0) + 1)
 
     # -- adaptive coalescing ------------------------------------------------
 

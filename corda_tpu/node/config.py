@@ -15,10 +15,7 @@ import json
 import os
 import tempfile
 
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: the API-identical backport
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 

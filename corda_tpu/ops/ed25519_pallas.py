@@ -23,8 +23,10 @@ Block anatomy (per 1024-lane block):
   * nibble scratch: 2 x (64, 8, 128) int32 = 512 KiB
   * output: (8, 128) int32 accept mask
 
-Semantics are bit-identical to the oracle and to verify_arrays (the
-conformance tests run this kernel in interpreter mode on CPU).
+Semantics are bit-identical to the oracle and to verify_arrays. The CPU
+tests compile this kernel for a described v5e (tests/test_chip_compile.py);
+chip_smoke.py runs it on the chip and checks every lane against the host
+tier and a sample against the oracle.
 """
 
 from __future__ import annotations

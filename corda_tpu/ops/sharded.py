@@ -26,13 +26,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from . import ed25519_jax, fe25519 as fe
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _NO_CHECK = {"check_vma": False}
-else:  # jax < 0.6: experimental path, and the kwarg was named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NO_CHECK = {"check_rep": False}
-
 __all__ = ["make_mesh", "sharded_verify_fn", "sharded_verify_hashed_fn",
            "verify_batch_sharded", "pad_to_devices",
            "pack_batch_sharded", "dispatch_packed", "PackedShardedBatch"]
@@ -78,18 +71,15 @@ def _sharded_fn(graph_fn, mesh: Mesh):
     key = (graph_fn, mesh)
     fn = _FN_CACHE.get(key)
     if fn is None:
-        # Route the sharded compiles through the host_cpu_signature()-keyed
-        # persistent cache (MULTICHIP_r05 tail: "Compile machine features
-        # ... doesn't match" — an XLA:CPU AOT artifact compiled on one
-        # machine type was loaded on another; the keyed dir partitions the
-        # cache per CPU feature set so stale artifacts are never loaded).
+        # Sharded compiles share the checkout's persistent cache
+        # (ops.compile_cache_dir) with the single-chip kernels.
         from . import enable_persistent_compile_cache
 
         enable_persistent_compile_cache()
         # lint: allow(no-jit-in-hotpath) this IS the keyed executable cache the rule routes hot paths through: one shard_map+jit per (graph, mesh), stored in _FN_CACHE above
-        inner = _shard_map(
+        inner = jax.shard_map(
             graph_fn, mesh=mesh, in_specs=_IN_SPECS, out_specs=_OUT_SPEC,
-            **_NO_CHECK,
+            check_vma=False,
         )
         # lint: allow(no-jit-in-hotpath) cache-miss arm of _FN_CACHE: compiled once per key, then every dispatch reuses the stored executable
         fn = _FN_CACHE[key] = jax.jit(inner)

@@ -454,10 +454,7 @@ def fire_disk_full() -> None:
 
 def plan_from_toml(text: str, node_name: str | None = None) -> FaultPlan:
     """Parse a TOML plan (see module docstring for the format)."""
-    try:
-        import tomllib
-    except ModuleNotFoundError:  # Python < 3.11
-        import tomli as tomllib
+    import tomllib
 
     data = tomllib.loads(text)
     seed = int(data.get("seed", 0))

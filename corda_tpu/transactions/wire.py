@@ -71,7 +71,7 @@ class WireTransaction:
         KNOWN MALLEABILITY (inherited, reference parity): the id covers only
         inputs/outputs/attachments/commands — exactly the reference snapshot's
         calculateLeavesHashes — so notary, signers, type and timestamp can be
-        re-encoded by a relayer without changing the id or invalidating
+        re-encoded by an intermediary without changing the id or invalidating
         signatures. Later upstream versions add those fields as extra leaves;
         here we keep bit-parity with the snapshot. The id cross-check in
         SignedTransaction.tx catches component tampering only; altered

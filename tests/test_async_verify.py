@@ -425,9 +425,8 @@ def test_bench_contract_smoke_with_async_loadtest(monkeypatch, capsys):
                 "verify_batches": res.verify_batches}
 
     _stub_phases(monkeypatch)
-    monkeypatch.setattr(bench, "_install_watchdog", lambda *a: None)
     monkeypatch.setattr(bench, "bench_raft_cluster", mini_cluster)
-    bench.main()
+    assert bench.main() == 0
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == 1  # the one-line driver contract
     report = json.loads(out[0])

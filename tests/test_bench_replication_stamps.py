@@ -60,15 +60,6 @@ def test_raft_bench_section_emits_replication_stamps(tmp_path, monkeypatch,
     # THIS guard exists to drive the real one (over a faked sweep), so put
     # it back.
     monkeypatch.setattr(bench, "bench_raft_open_loop", _REAL_RAFT_OPEN_LOOP)
-    monkeypatch.setattr(bench, "_install_watchdog", lambda *a: None)
-    # Degraded (host-only) path: no device phases, but the raft open-loop
-    # config still measures — on the real bench_raft_open_loop. One init
-    # attempt: the inter-attempt flap backoff is 30 s of pure sleep.
-    monkeypatch.setenv("CORDA_TPU_DEVICE_INIT_RETRIES", "1")
-    monkeypatch.setattr(bench, "_device_init_with_timeout",
-                        lambda *a, **k: None)
-    monkeypatch.setattr(bench, "make_corpus",
-                        lambda *a: ([b"pk"], [b"m"], [b"s"], [True]))
 
     metrics = {"verifier": "cpu",
                "raft": _real_group_commit_stamp(tmp_path),
@@ -83,7 +74,7 @@ def test_raft_bench_section_emits_replication_stamps(tmp_path, monkeypatch,
 
     monkeypatch.setattr(loadtest, "run_latency_sweep", fake_sweep)
 
-    bench.main()
+    assert bench.main() == 0
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == 1  # the single-line contract survives the new keys
     report = json.loads(out[0])

@@ -181,6 +181,99 @@ def test_deadline_flush_bounds_a_lonely_request(sock_path):
         srv.stop()
 
 
+def test_oversized_request_verifies_in_bucket_slices(sock_path):
+    # One request larger than max_sigs must not dispatch a bucket the
+    # warm-up never compiled: the server verifies it in max_sigs slices
+    # and answers it whole, in order. Each slice is counted as the
+    # dispatch it was, with its own bucket and tier: a short last slice
+    # that routes to the host tier adds no device lanes.
+    sizes = []
+
+    class Recording(CpuVerifier):
+        device_batches = 0
+
+        def verify_batch(self, jobs):
+            sizes.append(len(jobs))
+            if len(jobs) >= 60:  # stands in for the size crossover
+                self.device_batches += 1
+            return super().verify_batch(jobs)
+
+    jobs = [j for j in _corpus() if len(j.pubkey) == 32
+            and len(j.sig) == 64 and j.scheme == "ed25519"]
+    n = len(jobs) * 30
+    want = CpuVerifier().verify_batch(jobs * 30)
+    srv = _server(sock_path, verifier=Recording(), max_sigs=64)
+    try:
+        cli = SidecarVerifier(sock_path, device_min_sigs=0,
+                              deadline_ms=10_000.0)
+        out = cli.verify_batch(jobs * 30)
+        assert out.tolist() == want.tolist()
+        assert max(sizes) <= 64 and sum(sizes) == n
+        stats = srv.stats()
+        assert stats["batches"] == 1 and stats["sigs"] == n
+        assert sizes == [64, 56]
+        assert stats["batch_sigs_hist"] == {"64": 2}
+        on_device = [64]  # the 56-signature slice took the host tier
+        lanes = sum(sc.bucket_for(k) for k in on_device)
+        assert stats["device_lanes"] == lanes
+        assert stats["pad_lanes"] == lanes - sum(on_device)
+        assert sum(stats["per_device_batch_sigs_hist"].values()) == len(
+            on_device)
+    finally:
+        srv.stop()
+
+
+class _BrokenWarmVerifier:
+    """A jax-tier verifier whose device warm-up fails."""
+
+    name = "jax-batch"
+    device_min_sigs = 512
+    device_gate = None
+
+    def warm(self):
+        raise RuntimeError("mosaic refused the kernel")
+
+
+def _await(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return cond()
+
+
+def test_sidecar_warm_failure_on_accelerator_ends_the_process(
+        monkeypatch, sock_path):
+    import jax
+
+    exits = []
+    monkeypatch.setattr(os, "_exit", exits.append)  # instead of dying
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = _server(sock_path, verifier=_BrokenWarmVerifier())
+    try:
+        assert _await(lambda: exits), "the warm failure did not exit"
+        assert exits == [70]
+        assert "mosaic refused" in srv.warm_error
+    finally:
+        srv.stop()
+
+
+def test_node_warm_failure_on_accelerator_ends_the_process(monkeypatch):
+    import types
+
+    import jax
+
+    exits = []
+    monkeypatch.setattr(os, "_exit", exits.append)
+    monkeypatch.setattr(jax, "devices", lambda: [
+        types.SimpleNamespace(platform="tpu")])
+    node = types.SimpleNamespace(
+        smm=types.SimpleNamespace(verifier=_BrokenWarmVerifier()),
+        config=types.SimpleNamespace(name="Raft0"))
+    Node._warm_verifier_maybe(node)
+    node._warm_thread.join(timeout=10.0)
+    assert exits == [70]
+
+
 def test_capacity_flush_beats_the_deadline(sock_path):
     # The window is far longer than the client deadline: only the early
     # flush at bucket capacity can answer in time.
@@ -425,26 +518,6 @@ def test_member_stamp_reports_occupancy_and_sidecar():
     empty = _member_stamp({}, device="cpu")
     assert empty["device_occupancy"] is None
     assert empty["sidecar"] is None
-
-
-# ---------------------------------------------------------------------------
-# Satellite: CPU-signature-keyed compile cache
-# ---------------------------------------------------------------------------
-
-
-def test_host_cpu_signature_keys_the_cache_dirs(monkeypatch):
-    from corda_tpu.ops import default_jax_cache_dir, host_cpu_signature
-    from corda_tpu.testing.driver import _node_env
-
-    sig = host_cpu_signature()
-    assert len(sig) == 8
-    assert sig == host_cpu_signature()  # deterministic
-    int(sig, 16)  # hex
-    assert default_jax_cache_dir().endswith(f"_{sig}")
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    env = _node_env("accelerator")
-    assert env["JAX_COMPILATION_CACHE_DIR"] == default_jax_cache_dir()
-    assert _node_env("cpu").get("JAX_PLATFORMS") == "cpu"
 
 
 # ---------------------------------------------------------------------------
