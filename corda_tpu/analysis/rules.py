@@ -39,6 +39,10 @@ def _last_attr(dotted: str) -> str:
     return dotted.rsplit(".", 1)[-1] if dotted else ""
 
 
+# obs.trace functions whose first argument is a span name.
+_SPAN_FNS = ("record", "span")
+
+
 class _Imports:
     """Module-alias table for one file: which local names refer to the
     ``time`` / ``datetime`` / obs ``trace`` / obs ``telemetry`` modules,
@@ -50,7 +54,8 @@ class _Imports:
         self.obs_trace_aliases: set[str] = set()
         self.telemetry_aliases: set[str] = set()
         self.clock_names: dict[str, str] = {}   # local name -> origin fn
-        self.record_names: set[str] = set()     # from obs.trace import record
+        # from obs.trace import record / span
+        self.record_names: set[str] = set()
         # local name -> "inc" | "observe"  (from obs.telemetry import ...)
         self.metric_fn_names: dict[str, str] = {}
         for node in ast.walk(tree):
@@ -77,7 +82,7 @@ class _Imports:
                             self.obs_trace_aliases.add(a.asname or a.name)
                         elif a.name == "telemetry":
                             self.telemetry_aliases.add(a.asname or a.name)
-                        elif a.name == "record" and mod.endswith("trace"):
+                        elif a.name in _SPAN_FNS and mod.endswith("trace"):
                             self.record_names.add(a.asname or a.name)
                         elif a.name in ("inc", "observe") and \
                                 mod.endswith("telemetry"):
@@ -560,7 +565,8 @@ class TraceStageRegistry(Rule):
     """``stage_breakdown`` attributes latency by exact span-name match; a
     span recorded under an unregistered name silently vanishes from the
     bench breakdown (no error — a missing stage). Every literal span name
-    passed to ``_obs.record(...)`` must come from the obs stage registry
+    passed to ``_obs.record(...)`` or ``_obs.span(...)`` must come from
+    the obs stage registry
     (corda_tpu/obs/stages.py). The telemetry plane has the same failure
     shape with the opposite sign: ``_tm.inc``/``_tm.observe`` on a name
     the registry never pre-interned RAISES at runtime — possibly only on
@@ -592,7 +598,7 @@ class TraceStageRegistry(Rule):
         if isinstance(func, ast.Name):
             return func.id in imports.record_names
         dotted = _dotted(func)
-        if _last_attr(dotted) != "record":
+        if _last_attr(dotted) not in _SPAN_FNS:
             return False
         root = dotted.split(".", 1)[0]
         return root in imports.obs_trace_aliases

@@ -39,6 +39,7 @@ from typing import NoReturn, Sequence
 
 import numpy as np
 
+from ..obs import trace as _obs
 from . import fast_ed25519, ref_ed25519
 
 
@@ -69,16 +70,26 @@ def _dispatch_mixed(jobs: Sequence[VerifyJob], ed25519_fn,
         from . import fast_ecdsa_p256
 
         p256_fn = fast_ecdsa_p256.verify
-    out = np.zeros(len(jobs), bool)
-    ed_idx = [i for i, j in enumerate(jobs) if j.scheme == "ed25519"]
+    with _obs.span("verify.prepare"):
+        out = np.zeros(len(jobs), bool)
+        ed_idx = [i for i, j in enumerate(jobs) if j.scheme == "ed25519"]
+        ed_jobs = [jobs[i] for i in ed_idx]
     if ed_idx:
-        ed_ok = ed25519_fn([jobs[i] for i in ed_idx])
+        ed_ok = ed25519_fn(ed_jobs)
+    with _obs.span("verify.scatter"):
         for k, i in enumerate(ed_idx):
             out[i] = ed_ok[k]
-    for i, job in enumerate(jobs):
-        if job.scheme == "ecdsa-p256":
-            out[i] = p256_fn(job.pubkey, job.message, job.sig)
+        for i, job in enumerate(jobs):
+            if job.scheme == "ecdsa-p256":
+                out[i] = p256_fn(job.pubkey, job.message, job.sig)
     return out
+
+
+def _columns(jobs: Sequence[VerifyJob]) -> tuple[list, list, list]:
+    """The device tiers' input: jobs as key, message and signature lists."""
+    with _obs.span("verify.prepare"):
+        return ([j.pubkey for j in jobs], [j.message for j in jobs],
+                [j.sig for j in jobs])
 
 
 class BatchVerifier:
@@ -232,7 +243,8 @@ class DeviceRoutedVerifier(BatchVerifier):
     def verify_batch(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         if not jobs:
             return np.zeros(0, bool)
-        return _dispatch_mixed(jobs, self._verify_ed25519)
+        with _obs.span("verify.batch", lanes=len(jobs)):
+            return _dispatch_mixed(jobs, self._verify_ed25519)
 
     def _verify_ed25519(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         if (len(jobs) < self.device_min_sigs
@@ -300,9 +312,7 @@ class JaxVerifier(DeviceRoutedVerifier):
     def _verify_ed25519_device(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         from ..ops import ed25519_jax
 
-        return ed25519_jax.verify_batch(
-            [j.pubkey for j in jobs], [j.message for j in jobs],
-            [j.sig for j in jobs])
+        return ed25519_jax.verify_batch(*_columns(jobs))
 
     def warm(self) -> None:
         from ..ops import ed25519_jax
@@ -349,9 +359,7 @@ class MeshVerifier(DeviceRoutedVerifier):
     def _verify_ed25519_device(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         from ..ops import sharded
 
-        return sharded.verify_batch_sharded(
-            [j.pubkey for j in jobs], [j.message for j in jobs],
-            [j.sig for j in jobs], self.mesh)
+        return sharded.verify_batch_sharded(*_columns(jobs), self.mesh)
 
     def pack_device(self, jobs: Sequence[VerifyJob]):
         """Host half of the mesh dispatch, routed EXACTLY like
@@ -368,9 +376,7 @@ class MeshVerifier(DeviceRoutedVerifier):
             return None
         from ..ops import sharded
 
-        return sharded.pack_batch_sharded(
-            [j.pubkey for j in jobs], [j.message for j in jobs],
-            [j.sig for j in jobs], self.mesh)
+        return sharded.pack_batch_sharded(*_columns(jobs), self.mesh)
 
     def verify_packed(self, packed) -> np.ndarray:
         from ..ops import sharded

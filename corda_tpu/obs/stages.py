@@ -10,8 +10,9 @@ the single source of truth both sides key on:
     breakdown can never drift from the registry;
   * the static invariant analyzer (``corda_tpu.analysis``, rule
     ``trace-stage-registry``) checks every literal span name passed to
-    ``_obs.record(...)`` anywhere in the tree against ``SPAN_NAMES`` /
-    ``SPAN_NAME_PREFIXES``, so an instrumentation site with a typo'd or
+    ``_obs.record(...)`` or ``_obs.span(...)`` anywhere in the tree
+    against ``SPAN_NAMES`` / ``SPAN_NAME_PREFIXES``, so an
+    instrumentation site with a typo'd or
     unregistered name fails tier-1 instead of silently dropping out of
     ``stage_breakdown``.
 
@@ -31,6 +32,7 @@ __all__ = [
     "DERIVED_STAGES",
     "STAGES",
     "MARKER_SPANS",
+    "VERIFY_SPANS",
     "SPAN_NAME_PREFIXES",
     "SPAN_NAMES",
 ]
@@ -89,10 +91,23 @@ STAGES = ("admission_wait", "epoch_wait", "queue_wait", "lane_queue_wait",
 MARKER_SPANS = ("raft_commit", "notary_process", "qos_flush",
                 "shard_handoff", "election")
 
+# The verify path's own layers, recorded per provider call through
+# ``trace.span`` (never per lane): the provider boundary (verify.batch,
+# stat ``lanes`` = jobs submitted) and, inside it, jobs to byte columns
+# (verify.prepare), columnar packing (verify.pack), transfer and enqueue
+# of the challenge and kernel (verify.dispatch, stats ``lanes`` = lanes
+# carrying a submitted signature and ``bucket`` = lanes dispatched), the
+# host blocked on the device's answer (verify.readback), and verdicts
+# back into job order (verify.scatter). Not breakdown stages: with a
+# profiler session they are host events in the device trace.
+VERIFY_SPANS = ("verify.batch", "verify.prepare", "verify.pack",
+                "verify.dispatch", "verify.readback", "verify.scatter")
+
 # Dynamic span families: a recorded name may start with one of these
 # prefixes (the root flow span is f"flow:{FlowClassName}").
 SPAN_NAME_PREFIXES = ("flow:",)
 
-# Every literal name a recording site may pass to SpanRecorder.record().
+# Every literal name a recording site may pass to SpanRecorder.record()
+# or trace.span().
 SPAN_NAMES = frozenset(BATCH_STAGES) | frozenset(DIRECT_STAGES) \
-    | frozenset(MARKER_SPANS)
+    | frozenset(MARKER_SPANS) | frozenset(VERIFY_SPANS)

@@ -29,6 +29,17 @@ TCP wire frame; in-process it rides ``Message.trace``. The request-id link
 map lets RaftMember (which sees only PutAllCommand.request_id at batch-seal
 time) recover the submitting flow's trace without plumbing trace arguments
 through the consensus API.
+
+Spans on the device trace's clock
+---------------------------------
+``span(name, **stats)`` times a block of host code for two consumers, each
+behind its own switch: a running ``jax.profiler`` session (the span lands
+in the profiler's trace as a host event named ``name`` carrying ``stats``,
+on the same clock as the device's operations) and ``ACTIVE`` (the span
+lands in the ring, parented to the current context). With neither on it
+returns one shared no-op object. This module never imports jax: the first
+kernel module that does installs the profiler's annotation type through
+``install_annotation``.
 """
 
 from __future__ import annotations
@@ -52,6 +63,8 @@ __all__ = [
     "record",
     "register_link",
     "pop_link",
+    "span",
+    "install_annotation",
 ]
 
 ENV_VAR = "CORDA_TPU_TRACE"
@@ -268,3 +281,90 @@ def now() -> float:
     """Epoch seconds — the one clock every span uses so multi-process
     snapshots merge without skew handling beyond NTP's."""
     return time.time()
+
+
+# ---------------------------------------------------------------------------
+# span(): one helper for the profiler's trace and the ring
+# ---------------------------------------------------------------------------
+
+# The profiler's host-event type (jax.profiler.TraceAnnotation), or None
+# until a module that imports jax installs it: called as
+# ``factory(name, **stats)`` for a context manager, and asked
+# ``factory.is_enabled()`` whether a profiler session is running.
+_ANNOTATION = None
+
+
+def install_annotation(factory) -> None:
+    """Route ``span`` into the profiler's trace while a session runs."""
+    global _ANNOTATION
+    _ANNOTATION = factory
+
+
+class _NoSpan:
+    """What ``span`` returns with the profiler off and the ring disarmed."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _LiveSpan:
+    """A span with at least one consumer: the profiler's annotation, the
+    ring, or both. In the ring it becomes the current context while open,
+    so spans opened inside it are its children."""
+
+    __slots__ = ("name", "stats", "annotation", "rec", "outer", "ids",
+                 "t_start")
+
+    def __init__(self, name: str, stats: dict, annotation, rec):
+        self.name = name
+        self.stats = stats
+        self.annotation = annotation
+        self.rec = rec
+
+    def __enter__(self):
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        if self.rec is not None:
+            self.outer = get_context()
+            trace_id = (self.outer[0] if self.outer is not None
+                        else new_trace_id())
+            self.ids = (trace_id, new_span_id())
+            set_context(*self.ids)
+            self.t_start = now()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self.rec is not None:
+            outer = self.outer
+            self.rec.record(self.name, self.t_start, now(),
+                            trace_id=self.ids[0], span_id=self.ids[1],
+                            parent=outer[1] if outer is not None else None,
+                            attrs=self.stats or None)
+            if outer is None:
+                clear_context()
+            else:
+                set_context(*outer)
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        return False
+
+
+def span(name: str, **stats):
+    """Context manager timing one host block under ``name``, with
+    ``stats`` (small ints) as the profiler event's stats and the ring
+    span's attrs. Off both switches it allocates nothing of its own."""
+    factory = _ANNOTATION
+    annotation = (factory(name, **stats)
+                  if factory is not None and factory.is_enabled() else None)
+    rec = ACTIVE
+    if annotation is None and rec is None:
+        return _NO_SPAN
+    return _LiveSpan(name, stats, annotation, rec)

@@ -38,11 +38,15 @@ import jax.numpy as jnp
 
 from . import enable_persistent_compile_cache
 from . import fe25519 as fe
+from ..obs import trace as _obs
 
 # Importing this module means kernels are coming: share compiled graphs
 # across processes (a driver cluster spawns five nodes; each would
 # otherwise pay the cold compile).
 enable_persistent_compile_cache()
+# The verify path's spans (obs.trace.span) land in a running profiler
+# session's trace, on the device's clock.
+_obs.install_annotation(jax.profiler.TraceAnnotation)
 from ..crypto import ref_ed25519 as ref
 
 __all__ = ["verify_batch", "precompute_batch", "verify_arrays", "pick_bucket",
@@ -427,21 +431,28 @@ def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:
     (reference: core/.../transactions/SignedTransaction.kt:83-87).
     """
     n = len(sigs)
-    ok_shape = np.zeros(n, bool)
-    good = [i for i in range(n)
-            if len(bytes(pubkeys[i])) == 32 and len(bytes(sigs[i])) == 64]
-    if not good:
-        return ok_shape
-    bucket = pick_bucket(len(good))
-    if _pallas_available():
-        bucket = max(bucket, 1024)  # Pallas blocks are 1024 lanes
-    gp = [pubkeys[i] for i in good]
-    gm = [msgs[i] for i in good]
-    gs = [sigs[i] for i in good]
-    verify_fn, arrays, _ = _precompute_auto(gp, gm, gs, bucket)
-    out = np.asarray(verify_fn(*arrays))
-    for j, i in enumerate(good):
-        ok_shape[i] = out[j]
+    with _obs.span("verify.prepare"):
+        ok_shape = np.zeros(n, bool)
+        good = [i for i in range(n)
+                if len(bytes(pubkeys[i])) == 32 and len(bytes(sigs[i])) == 64]
+        if not good:
+            return ok_shape
+        bucket = pick_bucket(len(good))
+        if _pallas_available():
+            bucket = max(bucket, 1024)  # Pallas blocks are 1024 lanes
+        gp = [pubkeys[i] for i in good]
+        gm = [msgs[i] for i in good]
+        gs = [sigs[i] for i in good]
+        hashed = device_hash_eligible(gm)
+    with _obs.span("verify.pack"):
+        verify_fn, arrays, _ = _precompute(gp, gm, gs, bucket, hashed)
+    with _obs.span("verify.dispatch", lanes=len(good), bucket=bucket):
+        pending = verify_fn(*arrays)
+    with _obs.span("verify.readback"):
+        out = np.asarray(pending)
+    with _obs.span("verify.scatter"):
+        for j, i in enumerate(good):
+            ok_shape[i] = out[j]
     return ok_shape
 
 
@@ -533,7 +544,14 @@ def device_hash_eligible(msgs) -> bool:
 
 def _precompute_auto(pubkeys, msgs, sigs, bucket: int | None):
     """Dispatch per device_hash_eligible. Returns (verify_fn, arrays, n)."""
-    if device_hash_eligible(msgs):
+    return _precompute(pubkeys, msgs, sigs, bucket,
+                       device_hash_eligible(msgs))
+
+
+def _precompute(pubkeys, msgs, sigs, bucket: int | None, hashed: bool):
+    """Pack for the device-hashed graph (`hashed`: every message is 32
+    bytes) or the host-hashed one. Returns (verify_fn, arrays, n)."""
+    if hashed:
         arrays, n = precompute_batch_device(pubkeys, msgs, sigs,
                                             bucket=bucket)
         return verify_arrays_hashed, arrays, n

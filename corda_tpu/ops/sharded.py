@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import ed25519_jax, fe25519 as fe
+from ..obs import trace as _obs
 
 __all__ = ["make_mesh", "sharded_verify_fn", "sharded_verify_hashed_fn",
            "verify_batch_sharded", "pad_to_devices",
@@ -152,22 +153,26 @@ def pack_batch_sharded(pubkeys, msgs, sigs,
     dispatches at the same bucket reuse one executable and never
     re-partition."""
     n = len(sigs)
-    good = [i for i in range(n)
-            if len(bytes(pubkeys[i])) == 32 and len(bytes(sigs[i])) == 64]
-    if not good:
-        return None
-    ndev = mesh.devices.size
-    bucket = pad_to_devices(ed25519_jax.pick_bucket(len(good)), ndev)
-    gp = [pubkeys[i] for i in good]
-    gm = [msgs[i] for i in good]
-    gs = [sigs[i] for i in good]
-    if ed25519_jax.device_hash_eligible(gm):
-        arrays, _ = ed25519_jax.precompute_batch_device(gp, gm, gs,
-                                                        bucket=bucket)
-        fn = sharded_verify_hashed_fn(mesh)
-    else:
-        arrays, _ = ed25519_jax.precompute_batch(gp, gm, gs, bucket=bucket)
-        fn = sharded_verify_fn(mesh)
+    with _obs.span("verify.prepare"):
+        good = [i for i in range(n)
+                if len(bytes(pubkeys[i])) == 32 and len(bytes(sigs[i])) == 64]
+        if not good:
+            return None
+        ndev = mesh.devices.size
+        bucket = pad_to_devices(ed25519_jax.pick_bucket(len(good)), ndev)
+        gp = [pubkeys[i] for i in good]
+        gm = [msgs[i] for i in good]
+        gs = [sigs[i] for i in good]
+        hashed = ed25519_jax.device_hash_eligible(gm)
+    with _obs.span("verify.pack"):
+        if hashed:
+            arrays, _ = ed25519_jax.precompute_batch_device(gp, gm, gs,
+                                                            bucket=bucket)
+            fn = sharded_verify_hashed_fn(mesh)
+        else:
+            arrays, _ = ed25519_jax.precompute_batch(gp, gm, gs,
+                                                     bucket=bucket)
+            fn = sharded_verify_fn(mesh)
     return PackedShardedBatch(n, good, arrays, fn, bucket, ndev)
 
 
@@ -175,10 +180,15 @@ def dispatch_packed(packed: PackedShardedBatch) -> np.ndarray:
     """Device half: run the mesh executable and scatter lane results back
     to the caller's index space (padded lanes verify False and are never
     visible — bool[packed.n] covers exactly the requested lanes)."""
-    ok = np.zeros(packed.n, bool)
-    out = np.asarray(packed.fn(*packed.arrays))
-    for j, i in enumerate(packed.good):
-        ok[i] = out[j]
+    with _obs.span("verify.dispatch", lanes=len(packed.good),
+                   bucket=packed.bucket):
+        pending = packed.fn(*packed.arrays)
+    with _obs.span("verify.readback"):
+        out = np.asarray(pending)
+    with _obs.span("verify.scatter"):
+        ok = np.zeros(packed.n, bool)
+        for j, i in enumerate(packed.good):
+            ok[i] = out[j]
     return ok
 
 

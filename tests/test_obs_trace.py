@@ -288,9 +288,16 @@ def test_disarmed_path_allocates_nothing(net, monkeypatch):
     def _boom(*a, **kw):  # any span/id allocation while disarmed is a bug
         raise AssertionError("tracing touched while disarmed")
 
+    class _NoAnnotation:  # the profiler's annotation, no session running
+        is_enabled = staticmethod(lambda: False)
+        __init__ = _boom
+
     monkeypatch.setattr(obs, "new_trace_id", _boom)
     monkeypatch.setattr(obs, "new_span_id", _boom)
     monkeypatch.setattr(obs.SpanRecorder, "record", _boom)
+    # The verify path's spans (provider._dispatch_mixed on every batch).
+    monkeypatch.setattr(obs, "_LiveSpan", _boom)
+    monkeypatch.setattr(obs, "_ANNOTATION", _NoAnnotation)
     _notarise_move(net)
     # No envelope growth either: every message crossed with trace=None.
     assert net.messaging_network.sent_messages

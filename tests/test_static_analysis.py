@@ -419,6 +419,31 @@ class TestTraceStageRegistry:
         assert "trace-stage-registry" not in _rules(
             analyze_source(red, "corda_tpu/obs/collect.py"))
 
+    def test_unregistered_span_helper_literal_goes_red(self):
+        src = (
+            "from ..obs import trace as _obs\n"
+            "from ..obs.trace import span\n"
+            "def f():\n"
+            "    with _obs.span('verify.pakc'):\n"
+            "        pass\n"
+            "    with span('verify.dispatch', lanes=1, bucket=64):\n"
+            "        pass\n"
+            "    with span('verify.readbak'):\n"
+            "        pass\n"
+        )
+        report = analyze_source(src, "corda_tpu/ops/x.py")
+        assert _rules(report).count("trace-stage-registry") == 2
+
+    def test_registered_verify_spans_are_clean(self):
+        from corda_tpu.obs import stages
+
+        body = "".join(f"    with _obs.span({n!r}):\n        pass\n"
+                       for n in stages.VERIFY_SPANS)
+        src = "from ..obs import trace as _obs\ndef f():\n" + body
+        report = analyze_source(src, "corda_tpu/crypto/x.py")
+        assert "trace-stage-registry" not in _rules(report)
+        assert set(stages.VERIFY_SPANS) <= stages.SPAN_NAMES
+
     def test_registry_and_breakdown_share_one_source_of_truth(self):
         from corda_tpu.obs import collect, stages
 
