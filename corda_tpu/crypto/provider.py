@@ -32,6 +32,7 @@ ever warrants it — today none does.
 
 from __future__ import annotations
 
+import operator
 import os
 import random
 from dataclasses import dataclass
@@ -59,34 +60,51 @@ class VerifyJob:
     scheme: str = "ed25519"
 
 
+_SCHEME = operator.attrgetter("scheme")
+
+
+def _all_ed25519(jobs: Sequence[VerifyJob]) -> bool:
+    """Whether every job is Ed25519: the firehose and notary shape, which
+    skips the scheme split."""
+    return list(map(_SCHEME, jobs)).count("ed25519") == len(jobs)
+
+
 def _dispatch_mixed(jobs: Sequence[VerifyJob], ed25519_fn,
-                    p256_fn=None) -> np.ndarray:
-    """Split a mixed-scheme batch: the ed25519 subset goes to `ed25519_fn`
-    (each provider's batched path); ecdsa-p256 jobs verify through
-    `p256_fn` (default: the OpenSSL fast path with oracle-exact semantics,
+                    p256_fn=None, split: bool | None = None) -> np.ndarray:
+    """Verify a batch by scheme. An all-Ed25519 batch goes to `ed25519_fn`
+    (each provider's batched path) whole. Otherwise (`split`, computed
+    here when not given) the batch splits: the ed25519 subset goes to
+    `ed25519_fn`; ecdsa-p256 jobs verify through `p256_fn` (default: the
+    OpenSSL fast path with oracle-exact semantics,
     crypto/fast_ecdsa_p256.py); unknown schemes reject. Results recombine
     in input order."""
+    if split is None:
+        split = not _all_ed25519(jobs)
+    if not split:
+        return ed25519_fn(jobs) if len(jobs) else np.zeros(0, bool)
     if p256_fn is None:
         from . import fast_ecdsa_p256
 
         p256_fn = fast_ecdsa_p256.verify
     with _obs.span("verify.prepare"):
         out = np.zeros(len(jobs), bool)
-        ed_idx = [i for i, j in enumerate(jobs) if j.scheme == "ed25519"]
+        schemes = list(map(_SCHEME, jobs))
+        ed_idx = [i for i, s in enumerate(schemes) if s == "ed25519"]
         ed_jobs = [jobs[i] for i in ed_idx]
     if ed_idx:
         ed_ok = ed25519_fn(ed_jobs)
     with _obs.span("verify.scatter"):
-        for k, i in enumerate(ed_idx):
-            out[i] = ed_ok[k]
-        for i, job in enumerate(jobs):
-            if job.scheme == "ecdsa-p256":
+        if ed_idx:
+            out[ed_idx] = ed_ok
+        for i, s in enumerate(schemes):
+            if s == "ecdsa-p256":
+                job = jobs[i]
                 out[i] = p256_fn(job.pubkey, job.message, job.sig)
     return out
 
 
 def _columns(jobs: Sequence[VerifyJob]) -> tuple[list, list, list]:
-    """The device tiers' input: jobs as key, message and signature lists."""
+    """The mesh tier's input: jobs as key, message and signature lists."""
     with _obs.span("verify.prepare"):
         return ([j.pubkey for j in jobs], [j.message for j in jobs],
                 [j.sig for j in jobs])
@@ -229,6 +247,9 @@ class DeviceRoutedVerifier(BatchVerifier):
         self.device_min_sigs = _resolve_device_min_sigs(device_min_sigs)
         self.host_batches = 0
         self.device_batches = 0
+        # Batches of more than one scheme, which split by scheme and merge
+        # back (the all-Ed25519 shape goes to the ed25519 path whole).
+        self.split_batches = 0
         # node.py _warm_verifier_maybe installs its done-event here;
         # None (the default) means no gate. degrade_device() reuses the
         # same gate to host-route while the device tier is suspect.
@@ -243,8 +264,10 @@ class DeviceRoutedVerifier(BatchVerifier):
     def verify_batch(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         if not jobs:
             return np.zeros(0, bool)
-        with _obs.span("verify.batch", lanes=len(jobs)):
-            return _dispatch_mixed(jobs, self._verify_ed25519)
+        split = not _all_ed25519(jobs)
+        self.split_batches += split
+        with _obs.span("verify.batch", lanes=len(jobs), split=int(split)):
+            return _dispatch_mixed(jobs, self._verify_ed25519, split=split)
 
     def _verify_ed25519(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         if (len(jobs) < self.device_min_sigs
@@ -312,7 +335,7 @@ class JaxVerifier(DeviceRoutedVerifier):
     def _verify_ed25519_device(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         from ..ops import ed25519_jax
 
-        return ed25519_jax.verify_batch(*_columns(jobs))
+        return ed25519_jax.verify_jobs(jobs)
 
     def warm(self) -> None:
         from ..ops import ed25519_jax
@@ -372,7 +395,7 @@ class MeshVerifier(DeviceRoutedVerifier):
                 or len(jobs) < self.device_min_sigs
                 or (self.device_gate is not None
                     and not self.device_gate.is_set())
-                or any(j.scheme != "ed25519" for j in jobs)):
+                or not _all_ed25519(jobs)):
             return None
         from ..ops import sharded
 

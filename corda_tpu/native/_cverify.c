@@ -371,6 +371,250 @@ done:
     return result;
 }
 
+/* The fill of pack_jobs: well-formed lane j's key, R, S and message
+ * words go to column j of the four (8, B) word arrays. Big batches fan
+ * out over the verify pool's thread budget; each thread owns a range of
+ * columns. */
+typedef struct {
+    uint32_t **w;
+    Py_ssize_t B, lo, hi;
+    const unsigned char **pk, **sig, **msg;
+} fill_t;
+
+static void *fill_worker(void *arg) {
+    fill_t *f = (fill_t *)arg;
+    Py_ssize_t cnt = f->hi - f->lo;
+    fill_words(f->w[0] + f->lo, f->B, cnt, f->pk + f->lo, 0, 8);
+    fill_words(f->w[1] + f->lo, f->B, cnt, f->sig + f->lo, 0, 8);
+    fill_words(f->w[2] + f->lo, f->B, cnt, f->sig + f->lo, 32, 8);
+    fill_words(f->w[3] + f->lo, f->B, cnt, f->msg + f->lo, 0, 8);
+    return NULL;
+}
+
+#define FILL_PAR_MIN 16384
+
+static void fill_lanes(uint32_t **w, Py_ssize_t B, Py_ssize_t n,
+                       const unsigned char **pk, const unsigned char **sig,
+                       const unsigned char **msg) {
+    int nthreads = (int)(n / FILL_PAR_MIN);
+    if (nthreads > PAR_MAX_THREADS)
+        nthreads = PAR_MAX_THREADS;
+    long cores = sysconf(_SC_NPROCESSORS_ONLN);
+    if (cores > 0 && nthreads > cores)
+        nthreads = (int)cores;
+    if (nthreads < 1)
+        nthreads = 1;
+    fill_t parts[PAR_MAX_THREADS];
+    pthread_t tids[PAR_MAX_THREADS];
+    int started = 0;
+    Py_ssize_t chunk = (n + nthreads - 1) / nthreads;
+    for (int t = 0; t < nthreads; t++) {
+        Py_ssize_t lo = (Py_ssize_t)t * chunk;
+        Py_ssize_t hi = lo + chunk < n ? lo + chunk : n;
+        parts[t] = (fill_t){w, B, lo, hi, pk, sig, msg};
+        if (t < nthreads - 1
+            && pthread_create(&tids[started], NULL, fill_worker,
+                              &parts[t]) == 0) {
+            started++;
+            continue;
+        }
+        fill_worker(&parts[t]);
+    }
+    for (int t = 0; t < started; t++)
+        pthread_join(tids[t], NULL);
+}
+
+/* pack_jobs(jobs, pick_bucket) -> (mask, n_good, bucket, words) or None.
+ *
+ * The device path's whole host ingest in one pass over the job objects:
+ * reads each job's scheme, pubkey, message and sig by attribute (duck-typed
+ * jobs work), marks a lane well-formed when its key is 32 bytes and its
+ * signature 64 (the column path's accept set: ed25519_jax.verify_batch),
+ * calls pick_bucket(n_good) once, and packs the well-formed lanes
+ * contiguously into four (8, bucket) word arrays exactly as pack_words
+ * does, the fill running with the GIL RELEASED. `mask` is n bytes of 0/1,
+ * `words` the (a, r, s, m) bytes objects, or None when no lane is
+ * well-formed (pick_bucket is then never called).
+ *
+ * Returns None, for the caller's column path to answer, whenever this pass
+ * cannot be sure to match it: a job that is not Ed25519, an attribute that
+ * raises, a field that is not bytes, bytearray or a contiguous memoryview,
+ * or a well-formed lane whose message is not 32 bytes (the host-hashed
+ * path). Only allocation failures and pick_bucket's errors raise.
+ */
+typedef struct {
+    Py_buffer *views; /* allocated on the first field that needs one */
+    Py_ssize_t n, cap;
+} exports_t;
+
+/* One field of a job: 0 with its bytes in *ptr/*len, -1 where the column
+ * path must answer instead, -2 with an exception set. The attribute's
+ * reference goes to *held, which the caller releases. */
+static int job_field(PyObject *job, PyObject *name, PyObject **held,
+                     exports_t *ex, const unsigned char **ptr,
+                     Py_ssize_t *len) {
+    PyObject *v = PyObject_GetAttr(job, name);
+    if (v == NULL) {
+        PyErr_Clear();
+        return -1;
+    }
+    *held = v;
+    if (PyBytes_CheckExact(v)) {
+        *ptr = (const unsigned char *)PyBytes_AS_STRING(v);
+        *len = PyBytes_GET_SIZE(v);
+        return 0;
+    }
+    /* bytearray and memoryview through a buffer export, which also pins a
+     * bytearray's size while the GIL is released. */
+    if (!PyByteArray_CheckExact(v) && !PyMemoryView_Check(v))
+        return -1;
+    if (ex->views == NULL) {
+        ex->views = PyMem_Malloc((size_t)ex->cap * sizeof(Py_buffer));
+        if (ex->views == NULL) {
+            PyErr_NoMemory();
+            return -2;
+        }
+    }
+    if (PyObject_GetBuffer(v, &ex->views[ex->n], PyBUF_SIMPLE) != 0) {
+        PyErr_Clear();
+        return -1;
+    }
+    *ptr = ex->views[ex->n].buf;
+    *len = ex->views[ex->n].len;
+    ex->n++;
+    return 0;
+}
+
+static PyObject *pack_jobs(PyObject *self, PyObject *args) {
+    PyObject *jobs_in, *pick;
+    if (!PyArg_ParseTuple(args, "OO", &jobs_in, &pick))
+        return NULL;
+    static PyObject *names[4] = {NULL, NULL, NULL, NULL};
+    static const char *name_str[4] = {"scheme", "pubkey", "sig", "message"};
+    for (int k = 0; k < 4; k++) {
+        if (names[k] == NULL
+            && (names[k] = PyUnicode_InternFromString(name_str[k])) == NULL)
+            return NULL;
+    }
+    PyObject *seq = PySequence_Fast(jobs_in, "jobs must be a sequence");
+    if (seq == NULL)
+        return NULL;
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
+    PyObject *result = NULL, *mask = NULL, *bucket_obj = NULL;
+    PyObject *outs[4] = {NULL, NULL, NULL, NULL};
+    size_t slots = (size_t)(n ? n : 1) * 3;
+    PyObject **held = PyMem_Calloc(slots, sizeof(PyObject *));
+    const unsigned char **ptrs = PyMem_Malloc(slots * sizeof(*ptrs));
+    exports_t ex = {NULL, 0, 3 * n};
+    Py_ssize_t n_good = 0, bucket = 0;
+    int rc = 0;
+    if (held == NULL || ptrs == NULL) {
+        PyErr_NoMemory();
+        goto done;
+    }
+    mask = PyBytes_FromStringAndSize(NULL, n);
+    if (mask == NULL)
+        goto done;
+    char *m = PyBytes_AS_STRING(mask);
+    /* Well-formed lane j's pointers: ptrs[j] (key), ptrs[n + j] (sig),
+     * ptrs[2n + j] (message), compacted in input order. */
+    for (Py_ssize_t i = 0; i < n && rc == 0; i++) {
+        PyObject *job = PySequence_Fast_GET_ITEM(seq, i);
+        PyObject *scheme = PyObject_GetAttr(job, names[0]);
+        if (scheme == NULL) {
+            PyErr_Clear();
+            rc = -1;
+            break;
+        }
+        int ed = PyUnicode_CheckExact(scheme)
+                 && PyUnicode_CompareWithASCIIString(scheme, "ed25519") == 0;
+        Py_DECREF(scheme);
+        const unsigned char *p[3];
+        Py_ssize_t len[3];
+        if (!ed) {
+            rc = -1;
+            break;
+        }
+        if ((rc = job_field(job, names[1], &held[3 * i], &ex, &p[0],
+                            &len[0])) != 0
+            || (rc = job_field(job, names[2], &held[3 * i + 1], &ex, &p[1],
+                               &len[1])) != 0)
+            break;
+        m[i] = len[0] == 32 && len[1] == 64;
+        if (!m[i])
+            continue;
+        if ((rc = job_field(job, names[3], &held[3 * i + 2], &ex, &p[2],
+                            &len[2])) != 0)
+            break;
+        if (len[2] != 32) {
+            rc = -1;
+            break;
+        }
+        ptrs[n_good] = p[0];
+        ptrs[n + n_good] = p[1];
+        ptrs[2 * n + n_good] = p[2];
+        n_good++;
+    }
+    if (rc == -2)
+        goto done;
+    if (rc == -1) {
+        result = Py_NewRef(Py_None);
+        goto done;
+    }
+    if (n_good == 0) {
+        result = Py_BuildValue("(OnnO)", mask, (Py_ssize_t)0, (Py_ssize_t)0,
+                               Py_None);
+        goto done;
+    }
+    bucket_obj = PyObject_CallFunction(pick, "n", n_good);
+    if (bucket_obj == NULL)
+        goto done;
+    bucket = PyLong_AsSsize_t(bucket_obj);
+    if (bucket == -1 && PyErr_Occurred())
+        goto done;
+    if (bucket < n_good) {
+        PyErr_SetString(PyExc_ValueError, "bucket smaller than batch");
+        goto done;
+    }
+    for (int k = 0; k < 4; k++) {
+        outs[k] = PyBytes_FromStringAndSize(NULL, 8 * bucket * 4);
+        if (outs[k] == NULL)
+            goto done;
+    }
+    {
+        uint32_t *w[4];
+        for (int k = 0; k < 4; k++)
+            w[k] = (uint32_t *)PyBytes_AS_STRING(outs[k]);
+        Py_BEGIN_ALLOW_THREADS
+        for (int k = 0; k < 4; k++) {
+            for (Py_ssize_t row = 0; row < 8; row++)
+                memset(w[k] + row * bucket + n_good, 0,
+                       (size_t)(bucket - n_good) * 4);
+        }
+        fill_lanes(w, bucket, n_good, ptrs, ptrs + n, ptrs + 2 * n);
+        Py_END_ALLOW_THREADS
+    }
+    result = Py_BuildValue("(Onn(OOOO))", mask, n_good, bucket, outs[0],
+                           outs[1], outs[2], outs[3]);
+
+done:
+    for (Py_ssize_t k = 0; k < ex.n; k++)
+        PyBuffer_Release(&ex.views[k]);
+    if (held != NULL) {
+        for (Py_ssize_t k = 0; k < 3 * n; k++)
+            Py_XDECREF(held[k]);
+    }
+    PyMem_Free(held);
+    PyMem_Free(ex.views);
+    PyMem_Free(ptrs);
+    for (int k = 0; k < 4; k++)
+        Py_XDECREF(outs[k]);
+    Py_XDECREF(bucket_obj);
+    Py_XDECREF(mask);
+    Py_DECREF(seq);
+    return result;
+}
+
 /* sign_many(seeds, msgs) -> bytes (64 bytes of signature per job).
  *
  * The INGEST mirror of verify_many: columnar layout (seeds and msgs are
@@ -506,6 +750,11 @@ static PyMethodDef methods[] = {
      "pack_words(pks, msgs, sigs, bucket) -> (a, r, s, m) raw (8, bucket) "
      "uint32 word arrays for the device-hash verify path; GIL released "
      "during the fill."},
+    {"pack_jobs", pack_jobs, METH_VARARGS,
+     "pack_jobs(jobs, pick_bucket) -> (mask, n_good, bucket, (a, r, s, m)) "
+     "or None: one pass over Ed25519 job objects, well-formed lanes packed "
+     "contiguously into (8, bucket) word arrays; GIL released during the "
+     "fill."},
     {NULL, NULL, 0, NULL},
 };
 
