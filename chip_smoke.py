@@ -12,7 +12,10 @@ one process at a time, and this parent never imports JAX):
    hash path (``verify_arrays_hashed``). Every lane must agree with the
    host tier (``CpuVerifier``, computed here meanwhile), a seeded sample
    of 1,024 lanes with the pure-Python oracle, and the kernel backend must
-   be ``pallas`` with no failures and no degrade.
+   be ``pallas`` with no failures and no degrade. Then one call of 70,000
+   live lanes (the well-formed corpus, cycled) in the 131,072 bucket: the
+   kernel skips the 59 blocks past them, every live lane must agree with
+   the host tier and every dead lane must read reject.
 2. **notary** — the validating Raft notary as users deploy it: three
    members, one verification sidecar that owns the chip, two client
    processes offering the raft-notary-demo load of 1,000 transactions
@@ -48,6 +51,8 @@ N_LANES = 65536  # the largest bucket of the bench ladder (bench.BUCKETS)
 CORRUPT_EVERY = 8  # 1 in 8 signatures is damaged (R, S or message)
 ORACLE_SAMPLE = 1024
 SEED = 21
+PADDED_LIVE = 70000  # live lanes of the padded call: 69 of 128 blocks
+PADDED_BUCKET = 131072
 NOTARY_TX = 1000  # BASELINE.json raft-notary-demo
 # Offered loads for the notary phase, tried in order until the sidecar
 # serves a device batch: the first is the loadtest's default shape.
@@ -143,9 +148,21 @@ class _CompileMeter:
                 "cache_hits": self.cache_hits}
 
 
+def well_formed(jobs) -> list:
+    """Indices of the jobs with a 32-byte key and a 64-byte signature."""
+    return [i for i, j in enumerate(jobs)
+            if len(j.pubkey) == 32 and len(j.sig) == 64]
+
+
+def padded_sources(good: list) -> list:
+    """The corpus lane behind each live lane of the padded call."""
+    return [good[k % len(good)] for k in range(PADDED_LIVE)]
+
+
 def kernel_phase(send, seed: int) -> dict:
     """Child body: the 65,536-lane corpus through JaxVerifier and through
-    verify_arrays_hashed. Returns both answers and the counters."""
+    verify_arrays_hashed, then the padded call. Returns the answers and
+    the counters."""
     import numpy as np
 
     from corda_tpu.crypto.provider import JaxVerifier
@@ -175,8 +192,7 @@ def kernel_phase(send, seed: int) -> dict:
 
     # The fully-on-device path: SHA-512 challenge + mod L + verify, with
     # the well-formed lanes packed into one 65,536-lane bucket.
-    good = [i for i, j in enumerate(jobs)
-            if len(j.pubkey) == 32 and len(j.sig) == 64]
+    good = well_formed(jobs)
     arrays, n = ed25519_jax.precompute_batch_device(
         [jobs[i].pubkey for i in good], [jobs[i].message for i in good],
         [jobs[i].sig for i in good], bucket=N_LANES)
@@ -188,6 +204,20 @@ def kernel_phase(send, seed: int) -> dict:
     hashed_ok = np.zeros(len(jobs), bool)
     hashed_ok[good] = lanes[:n]
     out["hashed_backend"] = ed25519_jax.last_backend()
+
+    src = padded_sources(good)
+    arrays, _ = ed25519_jax.precompute_batch_device(
+        [jobs[i].pubkey for i in src], [jobs[i].message for i in src],
+        [jobs[i].sig for i in src], bucket=PADDED_BUCKET)
+    skipped = ed25519_jax.pallas_blocks_skipped_total()
+    t0 = time.perf_counter()
+    padded = np.asarray(ed25519_jax.verify_arrays_hashed(
+        *arrays, live=PADDED_LIVE))
+    out["padded_first_s"] = time.perf_counter() - t0
+    out["padded_backend"] = ed25519_jax.last_backend()
+    out["padded_blocks_skipped"] = (
+        ed25519_jax.pallas_blocks_skipped_total() - skipped)
+    out["padded_ok"] = np.packbits(padded).tobytes()
     out["pallas_failures_total"] = ed25519_jax.pallas_failures_total()
     out["compile"] = meter.snapshot()
     out["provider_ok"] = np.packbits(provider_ok).tobytes()
@@ -250,8 +280,7 @@ def mesh_phase(send, seed: int, n_devices: int = 4) -> dict:
         "errors", "verifier")}
 
     # Where the lanes went: the mesh executable's output shards.
-    good = [i for i, j in enumerate(jobs)
-            if len(j.pubkey) == 32 and len(j.sig) == 64]
+    good = well_formed(jobs)
     packed = sharded.pack_batch_sharded(
         [jobs[i].pubkey for i in good], [jobs[i].message for i in good],
         [jobs[i].sig for i in good], mesh_verifier.mesh)
@@ -306,7 +335,7 @@ def host_answers(seed: int) -> dict:
     check(int((~host).sum()) >= expect_reject,
           "host tier accepted damaged signatures")
     return {"host": host, "oracle": oracle, "host_s": host_s,
-            "host_rejects": int((~host).sum())}
+            "host_rejects": int((~host).sum()), "good": well_formed(jobs)}
 
 
 def _unpack(bits: bytes):
@@ -328,6 +357,24 @@ def check_lanes(name: str, got, refs: dict) -> dict:
     check(not oracle_bad, f"{name}: oracle sample mismatch at {oracle_bad}")
     return {"lanes": int(got.size), "agree_host": int(got.size),
             "agree_oracle": len(refs["oracle"]),
+            "accepted": int(got.sum())}
+
+
+def check_padded(name: str, bits: bytes, refs: dict) -> dict:
+    """The padded call: live lanes as the host tier answers their corpus
+    lanes, dead lanes reject."""
+    import numpy as np
+
+    got = np.unpackbits(np.frombuffer(bits, np.uint8))[:PADDED_BUCKET]
+    got = got.astype(bool)
+    want = refs["host"][padded_sources(refs["good"])]
+    bad = np.flatnonzero(got[:PADDED_LIVE] != want)
+    check(bad.size == 0,
+          f"{name}: {bad.size} live lanes of the padded call disagree with "
+          f"the host tier (first {bad[:8].tolist()})")
+    dead = int(got[PADDED_LIVE:].sum())
+    check(dead == 0, f"{name}: {dead} dead lanes of the padded call accepted")
+    return {"live": PADDED_LIVE, "bucket": PADDED_BUCKET,
             "accepted": int(got.sum())}
 
 
@@ -396,10 +443,16 @@ def _kernel_run(name: str, seed: int, refs: dict) -> dict:
     for key in ("provider_ok", "hashed_ok"):
         res[key.replace("_ok", "_lanes")] = check_lanes(
             f"{name}/{key}", _unpack(res.pop(key)), refs)
-    check(res["provider_backend"] == "pallas"
-          and res["hashed_backend"] == "pallas",
-          f"{name}: kernel backend {res['provider_backend']}/"
-          f"{res['hashed_backend']}, want pallas")
+    res["padded_lanes"] = check_padded(f"{name}/padded",
+                                       res.pop("padded_ok"), refs)
+    backends = [res[k] for k in ("provider_backend", "hashed_backend",
+                                 "padded_backend")]
+    check(backends == ["pallas"] * 3,
+          f"{name}: kernel backends {backends}, want pallas")
+    dead_blocks = (PADDED_BUCKET - PADDED_LIVE) // 1024
+    check(res["padded_blocks_skipped"] == dead_blocks,
+          f"{name}: the padded call skipped {res['padded_blocks_skipped']} "
+          f"blocks, want {dead_blocks}")
     check(res["pallas_failures_total"] == 0,
           f"{name}: {res['pallas_failures_total']} Pallas failures")
     check(res["degraded"] == 0, f"{name}: device tier degraded")

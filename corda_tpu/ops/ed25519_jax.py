@@ -51,6 +51,7 @@ from ..crypto import ref_ed25519 as ref
 
 __all__ = ["verify_batch", "verify_jobs", "precompute_batch", "verify_arrays",
            "pick_bucket", "verify_core", "pallas_failures_total",
+           "pallas_blocks_run_total", "pallas_blocks_skipped_total",
            "last_backend", "reset_pallas_state"]
 
 _D = ref.D
@@ -306,7 +307,10 @@ def verify_arrays(a_words, r_words, s_words, h_words):
 
 def pick_bucket(n: int, buckets=(64, 256, 1024, 4096, 16384, 65536)) -> int:
     """Static batch-size bucket: jit caches one executable per bucket instead
-    of recompiling per request size (p99 protection on the notary path)."""
+    of recompiling per request size (p99 protection on the notary path).
+    The padding costs the Pallas kernel no field work: the dispatch passes
+    the live count, and blocks past it are skipped (100,000 lanes run 98 of
+    the 131,072 bucket's 128 blocks)."""
     for b in buckets:
         if n <= b:
             return b
@@ -365,6 +369,8 @@ _PALLAS_STATE = {
     "available": None,        # None = unprobed; platform capability only
     "failures_total": 0,
     "last_backend": None,     # "pallas" | "xla": backend of the newest call
+    "blocks_run_total": 0,    # kernel blocks that held a live lane
+    "blocks_skipped_total": 0,  # padding-only blocks the kernel skipped
 }
 
 
@@ -391,15 +397,41 @@ def pallas_failures_total() -> int:
     return _PALLAS_STATE["failures_total"]
 
 
+def pallas_blocks_run_total() -> int:
+    """1024-lane kernel blocks run in this process: those with a live lane."""
+    return _PALLAS_STATE["blocks_run_total"]
+
+
+def pallas_blocks_skipped_total() -> int:
+    """Padding-only kernel blocks skipped in this process."""
+    return _PALLAS_STATE["blocks_skipped_total"]
+
+
 def reset_pallas_state() -> None:
-    """Forget the platform probe and failure history (tests)."""
-    _PALLAS_STATE.update(available=None, failures_total=0, last_backend=None)
+    """Forget the platform probe, failure history and block counts (tests)."""
+    _PALLAS_STATE.update(available=None, failures_total=0, last_backend=None,
+                         blocks_run_total=0, blocks_skipped_total=0)
 
 
-def verify_arrays_auto(a_words, r_words, s_words, h_words):
+def _uses_pallas(n: int) -> bool:
+    """Whether a batch of `n` lanes runs the Pallas kernel (1024-lane
+    blocks) rather than the XLA graph."""
+    return _pallas_available() and n % 1024 == 0
+
+
+def _live_blocks(live: int) -> int:
+    """Kernel blocks holding one of the first `live` lanes: those it runs."""
+    return -(-live // 1024)
+
+
+def verify_arrays_auto(a_words, r_words, s_words, h_words, live=None):
     """Best backend for the word-array contract: the VMEM-resident Pallas
     kernel on TPU (batch a multiple of 1024), the plain XLA graph
     otherwise.
+
+    `live` (None for all) says the first `live` lanes carry signatures and
+    the rest are padding: the kernel skips the blocks past them, which
+    read reject. The XLA graph ignores it and verifies every lane.
 
     A Pallas failure RAISES (after counting it in pallas_failures_total()):
     answering from the XLA graph instead would hide a broken kernel behind
@@ -408,16 +440,21 @@ def verify_arrays_auto(a_words, r_words, s_words, h_words):
     batch does next, and counts it.
     """
     n = a_words.shape[1]
-    if _pallas_available() and n % 1024 == 0:
+    if _uses_pallas(n):
         from . import ed25519_pallas
 
+        live = n if live is None else int(live)
         try:
+            # An int32 operand, never static: one executable per bucket.
             out = ed25519_pallas.verify_arrays_pallas(
-                a_words, r_words, s_words, h_words)
+                a_words, r_words, s_words, h_words, np.int32(live))
         except Exception:
             _PALLAS_STATE["failures_total"] += 1
             raise
+        blocks = _live_blocks(live)
         _PALLAS_STATE["last_backend"] = "pallas"
+        _PALLAS_STATE["blocks_run_total"] += blocks
+        _PALLAS_STATE["blocks_skipped_total"] += n // 1024 - blocks
         return out
     _PALLAS_STATE["last_backend"] = "xla"
     return verify_arrays(a_words, r_words, s_words, h_words)
@@ -493,9 +530,13 @@ def verify_jobs(jobs) -> np.ndarray:
 
 
 def _dispatch(verify_fn, arrays, lanes: int, bucket: int) -> np.ndarray:
-    """Enqueue one packed call and wait for its verdicts on the host."""
-    with _obs.span("verify.dispatch", lanes=lanes, bucket=bucket):
-        pending = verify_fn(*arrays)
+    """Enqueue one packed call of `lanes` live lanes (packed from lane 0)
+    and wait for its verdicts on the host."""
+    stats = {"lanes": lanes, "bucket": bucket}
+    if _uses_pallas(bucket):
+        stats["blocks"] = _live_blocks(lanes)
+    with _obs.span("verify.dispatch", **stats):
+        pending = verify_fn(*arrays, live=lanes)
     with _obs.span("verify.readback"):
         return np.asarray(pending)
 
@@ -569,14 +610,14 @@ def _cpack_module():
     return _CPACK_CACHE[0]
 
 
-def verify_arrays_hashed(a_words, r_words, s_words, m_words):
+def verify_arrays_hashed(a_words, r_words, s_words, m_words, live=None):
     """End-to-end device verification for 32-byte messages: the challenge
     h = SHA-512(R||A||M) mod L is computed on device, then fed to the best
-    available verify backend (Pallas on TPU, XLA otherwise)."""
+    available verify backend (Pallas on TPU, XLA otherwise) with `live`."""
     from . import sha512_jax
 
     h_words = sha512_jax.challenge_words(r_words, a_words, m_words)
-    return verify_arrays_auto(a_words, r_words, s_words, h_words)
+    return verify_arrays_auto(a_words, r_words, s_words, h_words, live)
 
 
 def device_hash_eligible(msgs) -> bool:
@@ -624,7 +665,7 @@ def verify_stream(batches, bucket: int | None = None, depth: int = 2):
     pending = collections.deque()  # (device_out, n), oldest first
     for pubkeys, msgs, sigs in batches:
         verify_fn, arrays, n = _precompute_auto(pubkeys, msgs, sigs, bucket)
-        pending.append((verify_fn(*jax.device_put(arrays)), n))
+        pending.append((verify_fn(*jax.device_put(arrays), live=n), n))
         if len(pending) > depth:
             prev_out, prev_n = pending.popleft()
             yield np.asarray(prev_out)[:prev_n]

@@ -23,6 +23,12 @@ Block anatomy (per 1024-lane block):
   * nibble scratch: 2 x (64, 8, 128) int32 = 512 KiB
   * output: (8, 128) int32 accept mask
 
+Padding blocks cost no field work. The live count (the first `live` lanes
+carry signatures) reaches the kernel by scalar prefetch; a grid step past
+the last live block writes reject and re-reads no input (its index_map
+stays on the last live block). A 100,000-signature call in the 131,072
+bucket runs 98 of 128 blocks; a full bucket runs them all.
+
 Semantics are bit-identical to the oracle and to verify_arrays. The CPU
 tests compile this kernel for a described v5e (tests/test_chip_compile.py);
 chip_smoke.py runs it on the chip and checks every lane against the host
@@ -49,8 +55,24 @@ LANES_PER_BLOCK = SUBLANES * LANES  # 1024
 _BATCH = (SUBLANES, LANES)
 
 
-def _kernel(a_ref, r_ref, s_ref, h_ref, btab_ref, ok_ref,
+def _kernel(live_ref, a_ref, r_ref, s_ref, h_ref, btab_ref, ok_ref,
             snib_ref, hnib_ref):
+    # The block holds a live lane iff its first lane is one of the first
+    # `live`.
+    live = pl.program_id(0) * LANES_PER_BLOCK < live_ref[0]
+
+    @pl.when(jnp.logical_not(live))
+    def _():  # padding only: reject, no field work
+        ok_ref[0] = jnp.zeros(_BATCH, jnp.int32)
+
+    @pl.when(live)
+    def _():
+        _verify_block(a_ref, r_ref, s_ref, h_ref, btab_ref, ok_ref,
+                      snib_ref, hnib_ref)
+
+
+def _verify_block(a_ref, r_ref, s_ref, h_ref, btab_ref, ok_ref,
+                  snib_ref, hnib_ref):
     # Trace-time switch: inside the kernel every value lives in VMEM, so the
     # streaming "rows" convolution is strictly better than the gather form
     # (Mosaic has no XLA-simplifier pathology on the unrolled adds).
@@ -84,36 +106,49 @@ def _kernel(a_ref, r_ref, s_ref, h_ref, btab_ref, ok_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def verify_arrays_pallas(a_words, r_words, s_words, h_words,
+def verify_arrays_pallas(a_words, r_words, s_words, h_words, live=None,
                          interpret: bool = False):
     """Same contract as ed25519_jax.verify_arrays — (8, N) uint32 words in,
     bool (N,) out — executed as one VMEM-resident kernel per 1024-lane block.
     N must be a multiple of 1024 (pick_bucket sizes >= 1024 all are).
+
+    `live` (an int32 scalar operand, None for all N) counts the live lanes,
+    the first `live` of the batch. Blocks past the last live one read
+    reject and do no field work; one executable serves every count.
     """
     n = a_words.shape[1]
     if n % LANES_PER_BLOCK:
         raise ValueError(f"batch {n} not a multiple of {LANES_PER_BLOCK}")
     nb = n // LANES_PER_BLOCK
+    live = jnp.asarray(n if live is None else live, jnp.int32).reshape(1)
 
     def shape_in(w):  # (8, N) -> (nb, 8, 8, 128), blocks major
         return w.reshape(8, nb, SUBLANES, LANES).transpose(1, 0, 2, 3)
 
+    def in_block(i, live_ref):  # a dead step keeps the last live block
+        last = jnp.maximum(live_ref[0] - 1, 0) // LANES_PER_BLOCK
+        return jnp.minimum(i, last), 0, 0, 0
+
     ins = [shape_in(w) for w in (a_words, r_words, s_words, h_words)]
-    in_spec = pl.BlockSpec((1, 8, SUBLANES, LANES), lambda i: (i, 0, 0, 0),
+    in_spec = pl.BlockSpec((1, 8, SUBLANES, LANES), in_block,
                            memory_space=pltpu.VMEM)
-    btab_spec = pl.BlockSpec((3, 16, 20), lambda i: (0, 0, 0),
+    btab_spec = pl.BlockSpec((3, 16, 20), lambda i, live_ref: (0, 0, 0),
                              memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         _kernel,
-        grid=(nb,),
-        in_specs=[in_spec] * 4 + [btab_spec],
-        out_specs=pl.BlockSpec((1, SUBLANES, LANES), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb,),
+            in_specs=[in_spec] * 4 + [btab_spec],
+            out_specs=pl.BlockSpec((1, SUBLANES, LANES),
+                                   lambda i, live_ref: (i, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((64, SUBLANES, LANES), jnp.int32),
+                pltpu.VMEM((64, SUBLANES, LANES), jnp.int32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((nb, SUBLANES, LANES), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((64, SUBLANES, LANES), jnp.int32),
-            pltpu.VMEM((64, SUBLANES, LANES), jnp.int32),
-        ],
         interpret=interpret,
-    )(*ins, jnp.asarray(ej._B_TABLE))
+    )(live, *ins, jnp.asarray(ej._B_TABLE))
     return out.reshape(n).astype(bool)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+PALLAS_LANES = 2048  # two 1,024-lane blocks
 SHA_LANES = 65536  # the largest bucket (bench.BUCKETS)
 MESH_LANES = 16384  # 4,096 per device
 
@@ -57,13 +58,18 @@ def _words(n, sharding):
 
 
 def test_pallas_verify_kernel_compiles_for_v5e(one_chip):
+    """Two blocks and the live count: the scalar prefetch, the skip of
+    dead blocks and their clamped input blocks all pass Mosaic."""
     from corda_tpu.ops import ed25519_pallas
 
-    w = _words(ed25519_pallas.LANES_PER_BLOCK, one_chip)
-    compiled = ed25519_pallas.verify_arrays_pallas.lower(w, w, w, w).compile()
+    w = _words(PALLAS_LANES, one_chip)
+    live = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = ed25519_pallas.verify_arrays_pallas.lower(
+        w, w, w, w, live).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes == 4 * 8 * 1024 * 4
+    # The words, and the int32 live count in one 512-byte buffer.
+    assert mem.argument_size_in_bytes == 4 * 8 * PALLAS_LANES * 4 + 512
     assert mem.temp_size_in_bytes < 1 << 20  # everything lives in VMEM
 
 

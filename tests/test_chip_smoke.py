@@ -23,6 +23,9 @@ def tiny(monkeypatch):
     # crossover would host-route such a batch, so it is lowered here.
     monkeypatch.setattr(chip_smoke, "N_LANES", 64)
     monkeypatch.setattr(chip_smoke, "ORACLE_SAMPLE", 16)
+    # The padded call: 40 live lanes in the same 64-lane bucket.
+    monkeypatch.setattr(chip_smoke, "PADDED_LIVE", 40)
+    monkeypatch.setattr(chip_smoke, "PADDED_BUCKET", 64)
     monkeypatch.setenv("CORDA_TPU_DEVICE_MIN_SIGS", "0")
     monkeypatch.setattr(chip_smoke, "require_tpu", lambda n: {
         "platform": "cpu", "kind": "cpu", "count": n})
@@ -40,10 +43,19 @@ def test_kernel_phase_rehearsal_agrees_with_host_and_oracle(tiny):
     assert res["provider_backend"] == res["hashed_backend"] == "xla"
     assert res["provider_batches"] == {"device": 2, "host": 0}
     assert res["pallas_failures_total"] == 0 and res["degraded"] == 0
+    padded = chip_smoke.check_padded("padded", res["padded_ok"], refs)
+    assert padded["live"] == 40 and padded["accepted"] > 0
+    assert res["padded_backend"] == "xla"
+    assert res["padded_blocks_skipped"] == 0  # the XLA graph has no blocks
     # A lane flipped against the host answer is caught.
     provider[5] = not provider[5]
     with pytest.raises(chip_smoke.SmokeFailure, match="disagree"):
         chip_smoke.check_lanes("provider", provider, refs)
+    # So is a dead lane of the padded call that reads accept.
+    bits = np.unpackbits(np.frombuffer(res["padded_ok"], np.uint8))
+    bits[50] = 1
+    with pytest.raises(chip_smoke.SmokeFailure, match="dead lanes"):
+        chip_smoke.check_padded("padded", np.packbits(bits).tobytes(), refs)
 
 
 def test_mesh_phase_rehearsal_shards_evenly(tiny):

@@ -302,7 +302,7 @@ def _fake_tpu_pallas(monkeypatch, fake):
 def test_pallas_failure_raises_and_is_recorded(monkeypatch):
     # On a TPU a failing kernel must surface, not be answered by the 30x
     # slower XLA graph: the caller's degrade path decides and counts it.
-    def fail(a, r, s, h):
+    def fail(a, r, s, h, live):
         raise RuntimeError("mosaic regression")
 
     arr = _fake_tpu_pallas(monkeypatch, fail)
@@ -318,7 +318,7 @@ def test_pallas_failure_raises_and_is_recorded(monkeypatch):
 def test_pallas_failure_does_not_demote_the_next_call(monkeypatch):
     calls = {"pallas": 0}
 
-    def flaky(a, r, s, h):
+    def flaky(a, r, s, h, live):
         calls["pallas"] += 1
         if calls["pallas"] == 1:
             raise RuntimeError("transient allocator hiccup")
