@@ -280,11 +280,11 @@ def mesh_phase(send, seed: int, n_devices: int = 4) -> dict:
         "errors", "verifier")}
 
     # Where the lanes went: the mesh executable's output shards.
-    good = well_formed(jobs)
-    packed = sharded.pack_batch_sharded(
-        [jobs[i].pubkey for i in good], [jobs[i].message for i in good],
-        [jobs[i].sig for i in good], mesh_verifier.mesh)
-    lanes = packed.fn(*packed.arrays)
+    mesh = mesh_verifier.mesh
+    packed = sharded.pack(jobs, mesh)
+    graphs = sharded.graphs(mesh)
+    graph = graphs.hashed if packed.hashed else graphs.host_hashed
+    lanes = graph(*packed.arrays, live=packed.n_good)
     out["shards"] = [{"device": str(s.device), "lanes": int(s.data.shape[0])}
                      for s in lanes.addressable_shards]
     out["compile"] = meter.snapshot()

@@ -66,8 +66,8 @@ from typing import Sequence
 import numpy as np
 
 from ..obs import telemetry as _tm
-from .provider import (CpuVerifier, DeviceRoutedVerifier, VerifyJob,
-                       degrade_device)
+from ..node.verify_client import SidecarError, SidecarVerifier
+from .provider import DeviceRoutedVerifier, VerifyJob, degrade_device
 from .sidecar import LANE_CODE_BULK
 
 # Re-dispatch threshold: a primary slower than this is hedged on the
@@ -135,6 +135,8 @@ class FederatedVerifier(DeviceRoutedVerifier):
     """Routes verify batches across N host-local sidecars (module doc)."""
 
     name = "federation"  # like "sidecar": must NOT start with "jax"
+    MIN_SIGS_ENV = SidecarVerifier.MIN_SIGS_ENV
+    MIN_SIGS_DEFAULT = SidecarVerifier.MIN_SIGS_DEFAULT
 
     def __init__(self, hosts: Sequence[str], deadline_ms: float = 2000.0,
                  device_min_sigs: int | None = None,
@@ -142,14 +144,8 @@ class FederatedVerifier(DeviceRoutedVerifier):
                  connect_timeout_s: float = 1.0,
                  reprobe_cooldown_s: float | None = None,
                  devices: int | None = None):
-        from ..node.verify_client import (SIDECAR_MIN_SIGS_DEFAULT,
-                                          SidecarVerifier)
-
         if not hosts:
             raise ValueError("federation needs at least one host address")
-        if device_min_sigs is None:
-            device_min_sigs = int(os.environ.get(
-                "CORDA_TPU_SIDECAR_MIN_SIGS", SIDECAR_MIN_SIGS_DEFAULT))
         super().__init__(device_min_sigs=device_min_sigs)
         if hedge_ms is None:
             hedge_ms = float(os.environ.get(
@@ -278,8 +274,6 @@ class FederatedVerifier(DeviceRoutedVerifier):
         client's SidecarError) only when no host answered — this method
         doubles as the whole-tier degrade re-probe, so an internal
         fallback would re-open the gate while every host is still dead."""
-        from ..node.verify_client import SidecarError
-
         hint = self.qos_hint
         lane_code = hint[0] if hint is not None else None
         t_route = time.perf_counter()
@@ -341,11 +335,8 @@ class FederatedVerifier(DeviceRoutedVerifier):
     # -- the DeviceRoutedVerifier routing override -------------------------
 
     def _verify_ed25519(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
-        if (len(jobs) < self.device_min_sigs
-                or (self.device_gate is not None
-                    and not self.device_gate.is_set())):
-            self.host_batches += 1
-            return CpuVerifier._verify_ed25519_host(jobs)
+        if self._host_routed(jobs):
+            return self._verify_on_host(jobs)
         try:
             out = self._verify_ed25519_device(jobs)
         except Exception:
@@ -356,8 +347,7 @@ class FederatedVerifier(DeviceRoutedVerifier):
             self.fallbacks += 1
             if not any(c.healthy.is_set() for c in self.channels):
                 degrade_device(self, cooldown_s=self.reprobe_cooldown_s)
-            self.host_batches += 1
-            return CpuVerifier._verify_ed25519_host(jobs)
+            return self._verify_on_host(jobs)
         self.device_batches += 1
         return out
 
@@ -405,8 +395,6 @@ class FederatedVerifier(DeviceRoutedVerifier):
         """Ping every host; healthy if ANY answers (the cluster can boot
         while one simulated host is still coming up). Raises only when
         the whole federation is unreachable."""
-        from ..node.verify_client import SidecarError
-
         errors = []
         reached = 0
         for channel in self.channels:

@@ -103,13 +103,6 @@ def _dispatch_mixed(jobs: Sequence[VerifyJob], ed25519_fn,
     return out
 
 
-def _columns(jobs: Sequence[VerifyJob]) -> tuple[list, list, list]:
-    """The mesh tier's input: jobs as key, message and signature lists."""
-    with _obs.span("verify.prepare"):
-        return ([j.pubkey for j in jobs], [j.message for j in jobs],
-                [j.sig for j in jobs])
-
-
 class BatchVerifier:
     """Interface: verify many independent signatures at once."""
 
@@ -210,18 +203,9 @@ def _shadow_check(jobs: Sequence[VerifyJob], out: np.ndarray,
 # trades/s device-always vs ~120 host, over a slow link to the chip; not
 # re-measured on a directly attached chip). This is routing by size, not
 # a fallback. Overridable per verifier or via CORDA_TPU_DEVICE_MIN_SIGS;
-# 0 forces device-always.
+# 0 forces device-always. The remote tiers (node/verify_client.py,
+# crypto/federation.py) start from their own variable and default.
 DEVICE_MIN_SIGS_DEFAULT = 512
-
-
-def _resolve_device_min_sigs(value: int | None) -> int:
-    """Shared constructor policy for the size crossover (JaxVerifier and
-    MeshVerifier): explicit argument wins, else CORDA_TPU_DEVICE_MIN_SIGS,
-    else the measured default."""
-    if value is not None:
-        return value
-    return int(os.environ.get(
-        "CORDA_TPU_DEVICE_MIN_SIGS", DEVICE_MIN_SIGS_DEFAULT))
 
 
 class DeviceRoutedVerifier(BatchVerifier):
@@ -231,7 +215,11 @@ class DeviceRoutedVerifier(BatchVerifier):
     flight — the first kernel call in a process pays backend init +
     compile, measured stalling a notary ~100 s in-loop), and the
     host/device batch counters every stamp reads. Subclasses implement
-    the device dispatch (_verify_ed25519_device) and warm()."""
+    the device dispatch (_verify_ed25519_device)."""
+
+    # Where the size crossover starts when the constructor is given none.
+    MIN_SIGS_ENV = "CORDA_TPU_DEVICE_MIN_SIGS"
+    MIN_SIGS_DEFAULT = DEVICE_MIN_SIGS_DEFAULT
 
     def __init__(self, shadow_rate: float = 0.0,
                  rng: random.Random | None = None,
@@ -243,8 +231,12 @@ class DeviceRoutedVerifier(BatchVerifier):
         # only the starting point. Reads/writes stay single-threaded (the
         # run loop owns routing policy; the feeder thread only reads it
         # inside verify_batch — a stale read routes one batch, never
-        # corrupts state).
-        self.device_min_sigs = _resolve_device_min_sigs(device_min_sigs)
+        # corrupts state). Explicit argument, else MIN_SIGS_ENV, else
+        # MIN_SIGS_DEFAULT.
+        if device_min_sigs is None:
+            device_min_sigs = int(os.environ.get(self.MIN_SIGS_ENV,
+                                                 self.MIN_SIGS_DEFAULT))
+        self.device_min_sigs = device_min_sigs
         self.host_batches = 0
         self.device_batches = 0
         # Batches of more than one scheme, which split by scheme and merge
@@ -269,14 +261,23 @@ class DeviceRoutedVerifier(BatchVerifier):
         with _obs.span("verify.batch", lanes=len(jobs), split=int(split)):
             return _dispatch_mixed(jobs, self._verify_ed25519, split=split)
 
+    def _host_routed(self, jobs: Sequence[VerifyJob]) -> bool:
+        """Whether a batch takes the host tier: below the size crossover,
+        or while the device gate is closed (boot warm-up, degrade)."""
+        gate = self.device_gate
+        return (len(jobs) < self.device_min_sigs
+                or (gate is not None and not gate.is_set()))
+
+    def _verify_on_host(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
+        """Answer a batch on the host tier, which is oracle-exact by
+        construction (CpuVerifier doc): no shadow sampling needed."""
+        self.host_batches += 1
+        return CpuVerifier._verify_ed25519_host(jobs)
+
     def _verify_ed25519(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
-        if (len(jobs) < self.device_min_sigs
-                or (self.device_gate is not None
-                    and not self.device_gate.is_set())):
-            # Host tier is oracle-exact by construction (CpuVerifier doc);
-            # no shadow sampling needed on this route.
-            self.host_batches += 1
-            return CpuVerifier._verify_ed25519_host(jobs)
+        # A device failure raises to the node's _degrade_and_reverify.
+        if self._host_routed(jobs):
+            return self._verify_on_host(jobs)
         self.device_batches += 1
         out = self._verify_ed25519_device(jobs)
         _shadow_check(jobs, out, self.shadow_rate, self._rng)
@@ -285,27 +286,13 @@ class DeviceRoutedVerifier(BatchVerifier):
     def _verify_ed25519_device(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
         raise NotImplementedError
 
-    def pack_device(self, jobs: Sequence[VerifyJob]):
-        """Split seam for pipelined callers (the sidecar's double-buffered
-        executor): host-side columnar packing of a batch, separable from the
-        device dispatch, so batch N+1 packs while batch N runs on the
-        device. Returns an opaque handle for :meth:`verify_packed`, or None
-        when this batch would NOT take the device tier (size/gate routing
-        says host, mixed schemes, nothing well-formed) — the caller then
-        falls back to the ordinary verify_batch path, which routes
-        identically. Base verifiers don't support the split."""
-        return None
-
-    def verify_packed(self, packed) -> np.ndarray:
-        """Dispatch a handle produced by :meth:`pack_device`. Counts as a
-        device batch (routing was already decided at pack time)."""
-        raise NotImplementedError
-
     def warm(self) -> None:
         """Compile THIS verifier's device path at both pump bucket sizes,
         bypassing the gate/size routing. Blocking and exception-raising —
         the caller (node.py boot warm-up) owns gating and error policy."""
-        raise NotImplementedError
+        for n in WARM_SIZES:
+            self._verify_ed25519_device(
+                [VerifyJob(bytes(32), bytes(32), bytes(64))] * n)
 
 
 # Warm batch sizes covering the pump's REAL bucket ladder on every backend:
@@ -336,13 +323,6 @@ class JaxVerifier(DeviceRoutedVerifier):
         from ..ops import ed25519_jax
 
         return ed25519_jax.verify_jobs(jobs)
-
-    def warm(self) -> None:
-        from ..ops import ed25519_jax
-
-        for n in WARM_SIZES:
-            ed25519_jax.verify_batch([bytes(32)] * n, [bytes(32)] * n,
-                                     [bytes(64)] * n)
 
 
 class MeshVerifier(DeviceRoutedVerifier):
@@ -380,42 +360,34 @@ class MeshVerifier(DeviceRoutedVerifier):
         return self._mesh
 
     def _verify_ed25519_device(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
-        from ..ops import sharded
+        from ..ops import ed25519_jax, sharded
 
-        return sharded.verify_batch_sharded(*_columns(jobs), self.mesh)
+        return ed25519_jax.run(sharded.pack(jobs, self.mesh),
+                               sharded.graphs(self.mesh), len(jobs))
 
     def pack_device(self, jobs: Sequence[VerifyJob]):
-        """Host half of the mesh dispatch, routed EXACTLY like
-        _verify_ed25519: batches the size/gate crossover would host-route
-        return None (so the pipelined caller's fallback lands on the same
-        tier this verifier would have chosen), as do mixed-scheme batches
-        (the split path only accelerates the pure-ed25519 firehose shape)
-        and all-malformed batches (the host tier answers those for free)."""
-        if (not jobs
-                or len(jobs) < self.device_min_sigs
-                or (self.device_gate is not None
-                    and not self.device_gate.is_set())
-                or not _all_ed25519(jobs)):
+        """Host half of the mesh dispatch, for the sidecar's double-buffered
+        executor (batch N+1 packs while batch N runs): a handle for
+        verify_packed. Routed EXACTLY like _verify_ed25519: batches the
+        size/gate crossover would host-route return None (so the pipelined
+        caller's fallback lands on the same tier this verifier would have
+        chosen), as do mixed-scheme batches (the split path only
+        accelerates the pure-ed25519 firehose shape) and all-malformed
+        batches (the host tier answers those for free)."""
+        if not jobs or self._host_routed(jobs) or not _all_ed25519(jobs):
             return None
         from ..ops import sharded
 
-        return sharded.pack_batch_sharded(*_columns(jobs), self.mesh)
+        return sharded.pack(jobs, self.mesh)
 
     def verify_packed(self, packed) -> np.ndarray:
-        from ..ops import sharded
+        """Dispatch a pack_device handle: a device batch (routing was
+        decided at pack time)."""
+        from ..ops import ed25519_jax, sharded
 
         self.device_batches += 1
-        return sharded.dispatch_packed(packed)
-
-    def warm(self) -> None:
-        """Compile the SHARDED graphs this verifier actually dispatches
-        (warming the single-chip kernel would open the gate without the
-        mesh path ever compiling)."""
-        from ..ops import sharded
-
-        for n in WARM_SIZES:
-            sharded.verify_batch_sharded([bytes(32)] * n, [bytes(32)] * n,
-                                         [bytes(64)] * n, self.mesh)
+        return ed25519_jax.run(packed, sharded.graphs(self.mesh),
+                               len(packed.mask))
 
 
 # Exit status of a process whose device verifier failed its warm-up on

@@ -30,9 +30,9 @@ Server structure (mirrors async_verify.py's pipeline, one level up):
   executor thread  — dispatches the pre-packed arrays (verify_packed) or,
                      for host-routed/unpackable batches, one verify_batch
                      call on the server's verifier (the
-                     DeviceRoutedVerifier size/gate routing and the padded
-                     pick_bucket executable cache in ops/ed25519_jax are
-                     reused unchanged), then splits results per request.
+                     DeviceRoutedVerifier size/gate routing and the bucket
+                     ladder of ops/buckets.py are reused unchanged), then
+                     splits results per request.
   depth-2 buffering: a BoundedSemaphore(depth) between scheduler and
                      executor lets the scheduler coalesce AND pack the
                      NEXT batch while the current one runs on the device.
@@ -87,6 +87,7 @@ from typing import Sequence
 import numpy as np
 
 from ..obs import telemetry as _tm
+from ..ops.buckets import pad_to_devices, pick_bucket
 from .provider import VerifyJob, exit_on_warm_failure, make_verifier
 
 OP_VERIFY = 1
@@ -123,26 +124,6 @@ _VERIFY_REQ_HDR = struct.Struct("<BII")
 _VERIFY_QOS_REQ_HDR = struct.Struct("<BIIBQ")
 _REPLY_HDR = struct.Struct("<BIB")
 _VERIFY_REPLY_HDR = struct.Struct("<BIBBff")
-
-# The kernel's padded-bucket ladder (ops/ed25519_jax.pick_bucket), mirrored
-# here so the batch-size histogram keys by executable bucket without this
-# module ever importing jax (stats must work on host-only processes).
-BUCKETS = (64, 256, 1024, 4096, 16384, 65536)
-
-
-def bucket_for(n: int) -> int:
-    for b in BUCKETS:
-        if n <= b:
-            return b
-    return BUCKETS[-1]
-
-
-def pad_to_devices(n: int, n_devices: int) -> int:
-    """Smallest multiple of n_devices >= max(n, n_devices) — mirrored from
-    ops/sharded.py (pure arithmetic) for the same reason BUCKETS mirrors
-    pick_bucket: pad attribution must work without importing jax."""
-    return -(-max(n, 1) // max(n_devices, 1)) * max(n_devices, 1)
-
 
 # Adaptive coalesce_us policy (ROADMAP item 1: grow the deadline from the
 # observed batch-size histogram so the mesh sees full buckets; shrink it
@@ -779,9 +760,9 @@ class SidecarServer:
     def _record_dispatch(self, n: int, tier: int, packed) -> None:
         """One dispatch of n signatures: its bucket and, on the device
         tier, its lanes. The packed handle knows the exact dispatched
-        bucket and mesh width; the unsplit device path is reconstructed
-        arithmetically (same ladder)."""
-        b = bucket_for(n)
+        bucket, well-formed count and mesh width; the unsplit device path
+        is reconstructed by the same rule (ops/buckets.py)."""
+        b = pick_bucket(n)
         with self._lock:
             self.batch_sigs_hist[b] = self.batch_sigs_hist.get(b, 0) + 1
             if not tier:
@@ -790,7 +771,7 @@ class SidecarServer:
                     else (self.mesh_devices or self.devices or 1))
             lanes = (packed.bucket if packed is not None
                      else pad_to_devices(b, ndev))
-            real = len(packed.good) if packed is not None else n
+            real = packed.n_good if packed is not None else n
             self.device_lanes += lanes
             self.pad_lanes += lanes - real
             share = lanes // ndev

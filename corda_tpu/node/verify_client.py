@@ -28,7 +28,6 @@ Failure policy — never a wrong answer, never a hang:
 
 from __future__ import annotations
 
-import os
 import socket
 import struct
 import threading
@@ -38,8 +37,7 @@ from typing import Sequence
 import numpy as np
 
 from ..crypto import sidecar as wire
-from ..crypto.provider import (CpuVerifier, DeviceRoutedVerifier, VerifyJob,
-                               degrade_device)
+from ..crypto.provider import DeviceRoutedVerifier, VerifyJob, degrade_device
 
 # Size crossover for the SIDECAR tier (see module doc: low on purpose —
 # the expensive device round trip happens server-side, amortised across
@@ -57,15 +55,14 @@ class SidecarVerifier(DeviceRoutedVerifier):
 
     name = "sidecar"  # must NOT start with "jax": the node's local warm
     #                   path and jax-only stamping do not apply here
+    MIN_SIGS_ENV = "CORDA_TPU_SIDECAR_MIN_SIGS"
+    MIN_SIGS_DEFAULT = SIDECAR_MIN_SIGS_DEFAULT
 
     def __init__(self, address: str, deadline_ms: float = 2000.0,
                  device_min_sigs: int | None = None,
                  connect_timeout_s: float = 1.0,
                  reprobe_cooldown_s: float | None = None,
                  devices: int | None = None):
-        if device_min_sigs is None:
-            device_min_sigs = int(os.environ.get(
-                "CORDA_TPU_SIDECAR_MIN_SIGS", SIDECAR_MIN_SIGS_DEFAULT))
         super().__init__(device_min_sigs=device_min_sigs)
         self.address = address
         self.deadline_s = float(deadline_ms) / 1e3
@@ -115,11 +112,8 @@ class SidecarVerifier(DeviceRoutedVerifier):
     # -- routing ------------------------------------------------------------
 
     def _verify_ed25519(self, jobs: Sequence[VerifyJob]) -> np.ndarray:
-        if (len(jobs) < self.device_min_sigs
-                or (self.device_gate is not None
-                    and not self.device_gate.is_set())):
-            self.host_batches += 1
-            return CpuVerifier._verify_ed25519_host(jobs)
+        if self._host_routed(jobs):
+            return self._verify_on_host(jobs)
         try:
             out = self._verify_ed25519_device(jobs)
         except SidecarError:
@@ -127,8 +121,7 @@ class SidecarVerifier(DeviceRoutedVerifier):
             # re-probe) and answer from the oracle-exact host path.
             self.fallbacks += 1
             degrade_device(self, cooldown_s=self.reprobe_cooldown_s)
-            self.host_batches += 1
-            return CpuVerifier._verify_ed25519_host(jobs)
+            return self._verify_on_host(jobs)
         self.device_batches += 1
         return out
 

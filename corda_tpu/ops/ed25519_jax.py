@@ -29,7 +29,9 @@ math, which is ~3,800 field multiplies per signature on device).
 
 from __future__ import annotations
 
+import collections
 import hashlib
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -38,6 +40,7 @@ import jax.numpy as jnp
 
 from . import enable_persistent_compile_cache
 from . import fe25519 as fe
+from .buckets import BLOCK_LANES, pick_bucket
 from ..obs import trace as _obs
 
 # Importing this module means kernels are coming: share compiled graphs
@@ -49,8 +52,10 @@ enable_persistent_compile_cache()
 _obs.install_annotation(jax.profiler.TraceAnnotation)
 from ..crypto import ref_ed25519 as ref
 
-__all__ = ["verify_batch", "verify_jobs", "precompute_batch", "verify_arrays",
-           "pick_bucket", "verify_core", "pallas_failures_total",
+__all__ = ["verify_batch", "verify_jobs", "verify_stream", "pack",
+           "pack_columns", "run", "run_stream", "Packed", "Graphs",
+           "precompute_batch", "verify_arrays", "pick_bucket", "verify_core",
+           "pallas_failures_total",
            "pallas_blocks_run_total", "pallas_blocks_skipped_total",
            "last_backend", "reset_pallas_state"]
 
@@ -305,18 +310,6 @@ def verify_arrays(a_words, r_words, s_words, h_words):
                        _nibbles_msb(s_words), _nibbles_msb(h_words))
 
 
-def pick_bucket(n: int, buckets=(64, 256, 1024, 4096, 16384, 65536)) -> int:
-    """Static batch-size bucket: jit caches one executable per bucket instead
-    of recompiling per request size (p99 protection on the notary path).
-    The padding costs the Pallas kernel no field work: the dispatch passes
-    the live count, and blocks past it are skipped (100,000 lanes run 98 of
-    the 131,072 bucket's 128 blocks)."""
-    for b in buckets:
-        if n <= b:
-            return b
-    return -(-n // buckets[-1]) * buckets[-1]
-
-
 def _words_of(enc: np.ndarray) -> np.ndarray:
     """(B, 32) uint8 little-endian encodings -> (8, B) uint32 words."""
     return np.ascontiguousarray(enc).view("<u4").T.copy()
@@ -375,12 +368,7 @@ _PALLAS_STATE = {
 
 
 def _pallas_available() -> bool:
-    """The Mosaic kernel runs on a TPU backend (CPU runs the XLA graph);
-    CORDA_TPU_NO_PALLAS=1 forces the XLA path for A/B comparison."""
-    import os
-
-    if os.environ.get("CORDA_TPU_NO_PALLAS"):
-        return False
+    """The Mosaic kernel runs on a TPU backend (CPU runs the XLA graph)."""
     if _PALLAS_STATE["available"] is None:
         _PALLAS_STATE["available"] = jax.devices()[0].platform == "tpu"
     return _PALLAS_STATE["available"]
@@ -416,12 +404,12 @@ def reset_pallas_state() -> None:
 def _uses_pallas(n: int) -> bool:
     """Whether a batch of `n` lanes runs the Pallas kernel (1024-lane
     blocks) rather than the XLA graph."""
-    return _pallas_available() and n % 1024 == 0
+    return _pallas_available() and n % BLOCK_LANES == 0
 
 
 def _live_blocks(live: int) -> int:
     """Kernel blocks holding one of the first `live` lanes: those it runs."""
-    return -(-live // 1024)
+    return -(-live // BLOCK_LANES)
 
 
 def verify_arrays_auto(a_words, r_words, s_words, h_words, live=None):
@@ -454,91 +442,10 @@ def verify_arrays_auto(a_words, r_words, s_words, h_words, live=None):
         blocks = _live_blocks(live)
         _PALLAS_STATE["last_backend"] = "pallas"
         _PALLAS_STATE["blocks_run_total"] += blocks
-        _PALLAS_STATE["blocks_skipped_total"] += n // 1024 - blocks
+        _PALLAS_STATE["blocks_skipped_total"] += n // BLOCK_LANES - blocks
         return out
     _PALLAS_STATE["last_backend"] = "xla"
     return verify_arrays(a_words, r_words, s_words, h_words)
-
-
-def _device_bucket(n_good: int) -> int:
-    """The bucket a device call of `n_good` well-formed lanes takes."""
-    bucket = pick_bucket(n_good)
-    if _pallas_available():
-        bucket = max(bucket, 1024)  # Pallas blocks are 1024 lanes
-    return bucket
-
-
-def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:
-    """End-to-end batched verify: returns bool (len(sigs),).
-
-    Malformed inputs (wrong lengths, junk bytes) reject — never raise —
-    matching the reference where verify exceptions surface as rejection
-    (reference: core/.../transactions/SignedTransaction.kt:83-87).
-    """
-    n = len(sigs)
-    with _obs.span("verify.prepare"):
-        good = [i for i in range(n)
-                if len(bytes(pubkeys[i])) == 32 and len(bytes(sigs[i])) == 64]
-        if not good:
-            return np.zeros(n, bool)
-        bucket = _device_bucket(len(good))
-        gp = [pubkeys[i] for i in good]
-        gm = [msgs[i] for i in good]
-        gs = [sigs[i] for i in good]
-        hashed = device_hash_eligible(gm)
-    with _obs.span("verify.pack"):
-        verify_fn, arrays, _ = _precompute(gp, gm, gs, bucket, hashed)
-    out = _dispatch(verify_fn, arrays, len(good), bucket)
-    with _obs.span("verify.scatter"):
-        ok = np.zeros(n, bool)
-        ok[good] = out[:len(good)]
-    return ok
-
-
-def verify_jobs(jobs) -> np.ndarray:
-    """`verify_batch` over job objects (`scheme`, `pubkey`, `message`,
-    `sig` attributes; every scheme "ed25519"): returns bool (len(jobs),).
-
-    The native `pack_jobs` reads the job list once, straight into the
-    packed word arrays of the well-formed lanes and their mask, so no
-    per-job Python list is built; verdicts go back by the mask. Where it
-    declines (a message that is not 32 bytes takes the host-hashed graph;
-    a field that is not plain bytes; no native core), the jobs become
-    columns for `verify_batch`, which stays the behavioural authority.
-    """
-    pack_jobs = getattr(_cpack_module(), "pack_jobs", None)
-    packed = None
-    if pack_jobs is not None:
-        with _obs.span("verify.pack"):
-            packed = pack_jobs(jobs, _device_bucket)
-    if packed is None:
-        with _obs.span("verify.prepare"):
-            columns = ([j.pubkey for j in jobs], [j.message for j in jobs],
-                       [j.sig for j in jobs])
-        return verify_batch(*columns)
-    mask, n_good, bucket, raw = packed
-    if not n_good:
-        return np.zeros(len(mask), bool)
-    arrays = tuple(np.frombuffer(r, "<u4").reshape(8, bucket) for r in raw)
-    out = _dispatch(verify_arrays_hashed, arrays, n_good, bucket)
-    with _obs.span("verify.scatter"):
-        if n_good == len(mask):
-            return out[:n_good].copy()
-        ok = np.zeros(len(mask), bool)
-        ok[np.frombuffer(mask, bool)] = out[:n_good]
-        return ok
-
-
-def _dispatch(verify_fn, arrays, lanes: int, bucket: int) -> np.ndarray:
-    """Enqueue one packed call of `lanes` live lanes (packed from lane 0)
-    and wait for its verdicts on the host."""
-    stats = {"lanes": lanes, "bucket": bucket}
-    if _uses_pallas(bucket):
-        stats["blocks"] = _live_blocks(lanes)
-    with _obs.span("verify.dispatch", **stats):
-        pending = verify_fn(*arrays, live=lanes)
-    with _obs.span("verify.readback"):
-        return np.asarray(pending)
 
 
 def precompute_batch_device(pubkeys, msgs, sigs, bucket: int | None = None):
@@ -620,55 +527,168 @@ def verify_arrays_hashed(a_words, r_words, s_words, m_words, live=None):
     return verify_arrays_auto(a_words, r_words, s_words, h_words, live)
 
 
-def device_hash_eligible(msgs) -> bool:
-    """The ONE dispatch predicate for host- vs device-hashed verification
-    (shared by the single-chip and sharded tiers): all-32-byte messages
-    (tx ids) hash on device."""
-    return all(len(bytes(m)) == 32 for m in msgs)
+# The device tier's two halves: pack() turns a batch into one handle on the
+# host; run() dispatches it and places the verdicts. The single chip and the
+# mesh (ops/sharded.py) differ only in the bucket rule and the graphs.
 
 
-def _precompute_auto(pubkeys, msgs, sigs, bucket: int | None):
-    """Dispatch per device_hash_eligible. Returns (verify_fn, arrays, n)."""
-    return _precompute(pubkeys, msgs, sigs, bucket,
-                       device_hash_eligible(msgs))
+def _device_bucket(n_good: int) -> int:
+    """The single chip's bucket for a call of `n_good` well-formed lanes."""
+    bucket = pick_bucket(n_good)
+    if _pallas_available():
+        bucket = max(bucket, BLOCK_LANES)
+    return bucket
 
 
-def _precompute(pubkeys, msgs, sigs, bucket: int | None, hashed: bool):
-    """Pack for the device-hashed graph (`hashed`: every message is 32
-    bytes) or the host-hashed one. Returns (verify_fn, arrays, n)."""
-    if hashed:
-        arrays, n = precompute_batch_device(pubkeys, msgs, sigs,
-                                            bucket=bucket)
-        return verify_arrays_hashed, arrays, n
-    arrays, n = precompute_batch(pubkeys, msgs, sigs, bucket=bucket)
-    return verify_arrays_auto, arrays, n
+class Packed(NamedTuple):
+    """One batch packed for a device call, well-formed lanes first."""
+
+    mask: np.ndarray      # bool (n,): the caller's well-formed lanes
+    n_good: int           # their count: lanes 0..n_good-1 of the arrays
+    bucket: int           # lanes dispatched
+    arrays: tuple         # four (8, bucket) uint32 word arrays
+    hashed: bool          # the device hashes the challenge (32-byte msgs)
+    n_devices: int = 1    # devices the bucket is split over
+
+
+class Graphs(NamedTuple):
+    """A tier's verify graphs, each called as graph(*arrays, live=n_good)."""
+
+    hashed: Callable       # (A, R, S, M) words: challenge hashed on device
+    host_hashed: Callable  # (A, R, S, h) words: challenge from the host
+    pallas: bool           # may run the Pallas kernel (the `blocks` stat)
+
+
+SINGLE_CHIP = Graphs(verify_arrays_hashed, verify_arrays_auto, pallas=True)
+
+
+def pack(jobs, bucket_of) -> Packed | None:
+    """The host half for job objects (`scheme`, `pubkey`, `message`, `sig`
+    attributes; every scheme "ed25519"): None when no lane is well-formed.
+
+    The native `pack_jobs` reads the job list once, straight into the
+    word arrays of the well-formed lanes and their mask, so no per-job
+    Python list is built. Where it declines (a message that is not 32
+    bytes takes the host-hashed graph; a field that is not plain bytes; no
+    native core), the jobs become columns for pack_columns."""
+    pack_jobs = getattr(_cpack_module(), "pack_jobs", None)
+    if pack_jobs is not None:
+        with _obs.span("verify.pack"):
+            native = pack_jobs(jobs, bucket_of)
+        if native is not None:
+            mask, n_good, bucket, raw = native
+            if not n_good:
+                return None
+            return Packed(np.frombuffer(mask, bool), n_good, bucket,
+                          tuple(np.frombuffer(r, "<u4").reshape(8, bucket)
+                                for r in raw), True)
+    with _obs.span("verify.prepare"):
+        columns = ([j.pubkey for j in jobs], [j.message for j in jobs],
+                   [j.sig for j in jobs])
+    return pack_columns(*columns, bucket_of)
+
+
+def pack_columns(pubkeys, msgs, sigs, bucket_of) -> Packed | None:
+    """The host half for key, message and signature columns: None when no
+    lane is well-formed (a 32-byte key and a 64-byte signature). All
+    32-byte messages (tx ids) hash on the device; otherwise the host
+    hashes (precompute_batch)."""
+    n = len(sigs)
+    with _obs.span("verify.prepare"):
+        mask = np.fromiter((len(bytes(pubkeys[i])) == 32
+                            and len(bytes(sigs[i])) == 64
+                            for i in range(n)), bool, n)
+        n_good = int(np.count_nonzero(mask))
+        if not n_good:
+            return None
+        if n_good < n:
+            good = np.flatnonzero(mask).tolist()
+            pubkeys, msgs, sigs = ([col[i] for i in good]
+                                   for col in (pubkeys, msgs, sigs))
+        bucket = bucket_of(n_good)
+        hashed = all(len(bytes(m)) == 32 for m in msgs)
+    with _obs.span("verify.pack"):
+        packer = precompute_batch_device if hashed else precompute_batch
+        arrays, _ = packer(pubkeys, msgs, sigs, bucket=bucket)
+    return Packed(mask, n_good, bucket, arrays, hashed)
+
+
+def run_stream(items, graphs: Graphs, depth: int = 0):
+    """The device half: yields the verdicts, bool (lanes,), of each
+    (packed, lanes) item in order; a None handle answers all-reject
+    without a call.
+
+    Each handle's call is enqueued with its live count (the kernel skips
+    the blocks past it), read back, and its verdicts placed by the mask.
+    JAX dispatch is asynchronous, so up to ``depth`` calls stay in flight
+    while the next item is drawn: the host packs batch k+1 while the
+    device runs batch k."""
+    source = iter(items)
+    inflight = collections.deque()  # (packed, lanes, device_out)
+    while True:
+        item = next(source, None) if len(inflight) <= depth else None
+        if item is not None:
+            packed, lanes = item
+            out = None
+            if packed is not None:
+                stats = {"lanes": packed.n_good, "bucket": packed.bucket}
+                if graphs.pallas and _uses_pallas(packed.bucket):
+                    stats["blocks"] = _live_blocks(packed.n_good)
+                graph = graphs.hashed if packed.hashed else graphs.host_hashed
+                with _obs.span("verify.dispatch", **stats):
+                    out = graph(*packed.arrays, live=packed.n_good)
+            inflight.append((packed, lanes, out))
+            continue
+        if not inflight:
+            return
+        packed, lanes, out = inflight.popleft()
+        if packed is None:
+            yield np.zeros(lanes, bool)
+            continue
+        with _obs.span("verify.readback"):
+            out = np.asarray(out)
+        with _obs.span("verify.scatter"):
+            n_good = packed.n_good
+            if n_good == len(packed.mask):
+                ok = out[:n_good].copy()
+            else:
+                ok = np.zeros(len(packed.mask), bool)
+                ok[packed.mask] = out[:n_good]
+        yield ok
+
+
+def run(packed: Packed | None, graphs: Graphs, lanes: int) -> np.ndarray:
+    """run_stream for one call: the verdicts of the caller's `lanes`."""
+    return next(run_stream(((packed, lanes),), graphs))
+
+
+def verify_jobs(jobs) -> np.ndarray:
+    """Batched verify of job objects on this chip: bool (len(jobs),).
+    Malformed lanes reject."""
+    return run(pack(jobs, _device_bucket), SINGLE_CHIP, len(jobs))
+
+
+def verify_batch(pubkeys, msgs, sigs) -> np.ndarray:
+    """Batched verify of key, message and signature columns on this chip:
+    bool (len(sigs),).
+
+    Malformed inputs (wrong lengths, junk bytes) reject — never raise —
+    matching the reference where verify exceptions surface as rejection
+    (reference: core/.../transactions/SignedTransaction.kt:83-87).
+    """
+    return run(pack_columns(pubkeys, msgs, sigs, _device_bucket),
+               SINGLE_CHIP, len(sigs))
 
 
 def verify_stream(batches, bucket: int | None = None, depth: int = 2):
     """Pipelined streaming verify: yields one bool array per input batch,
     in order.
 
-    ``batches`` is an iterable of (pubkeys, msgs, sigs) triples. JAX
-    dispatch is asynchronous, so while up to ``depth`` batches are in
-    flight on the device the host packs the next one — host packing,
-    host->device transfer and kernel execution all overlap, which is
-    exactly the shape of a notary pump under sustained load. Peak device
-    residency is ``depth + 1`` batches (4 word arrays each): ``depth``
-    already dispatched plus the one being dispatched while the oldest is
-    read back. 2 suffices when transfer is fast; deeper helps when the
-    link is slow.
+    ``batches`` is an iterable of (pubkeys, msgs, sigs) triples; ``bucket``
+    fixes every call's bucket (default: the single chip's rule). Up to
+    ``depth`` batches stay in flight while the host packs the next one
+    (run_stream); peak device residency is ``depth + 1`` batches.
     """
-    import collections
-
-    import jax
-
-    pending = collections.deque()  # (device_out, n), oldest first
-    for pubkeys, msgs, sigs in batches:
-        verify_fn, arrays, n = _precompute_auto(pubkeys, msgs, sigs, bucket)
-        pending.append((verify_fn(*jax.device_put(arrays), live=n), n))
-        if len(pending) > depth:
-            prev_out, prev_n = pending.popleft()
-            yield np.asarray(prev_out)[:prev_n]
-    while pending:
-        prev_out, prev_n = pending.popleft()
-        yield np.asarray(prev_out)[:prev_n]
+    bucket_of = _device_bucket if bucket is None else (lambda n: bucket)
+    items = ((pack_columns(*b, bucket_of), len(b[2])) for b in batches)
+    yield from run_stream(items, SINGLE_CHIP, depth)

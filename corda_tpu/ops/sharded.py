@@ -21,15 +21,13 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import ed25519_jax, fe25519 as fe
-from ..obs import trace as _obs
+from . import ed25519_jax
+from .buckets import pad_to_devices, pick_bucket
 
 __all__ = ["make_mesh", "sharded_verify_fn", "sharded_verify_hashed_fn",
-           "verify_batch_sharded", "pad_to_devices",
-           "pack_batch_sharded", "dispatch_packed", "PackedShardedBatch"]
+           "pack", "graphs"]
 
 BATCH_AXIS = "sigs"
 
@@ -48,11 +46,6 @@ def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
             devices = devices[:n_devices]
     # lint: allow(no-jit-in-hotpath) make_mesh IS the mesh constructor; every caller memoises its result (provider.mesh, bench setup) — it never runs per batch
     return Mesh(np.array(devices), (BATCH_AXIS,))
-
-
-def pad_to_devices(n: int, n_devices: int) -> int:
-    """Smallest multiple of n_devices >= max(n, n_devices)."""
-    return -(-max(n, 1) // n_devices) * n_devices
 
 
 # Kernel array layout: four (8, N) uint32 word arrays, batch minor.
@@ -91,8 +84,8 @@ def sharded_verify_fn(mesh: Mesh):
     """jit-compiled SPMD verify over ``mesh``: same signature/semantics as
     ``ed25519_jax.verify_arrays`` but with the batch axis sharded.
 
-    The batch size must be a multiple of the mesh size (use
-    :func:`pad_to_devices`; padded lanes simply verify to False).
+    The batch size must be a multiple of the mesh size (see
+    ``ops.buckets.pad_to_devices``; padded lanes simply verify to False).
     """
     return _sharded_fn(ed25519_jax.verify_arrays.__wrapped__, mesh)
 
@@ -116,90 +109,21 @@ def sharded_verify_hashed_fn(mesh: Mesh):
     return _sharded_fn(_verify_hashed_graph, mesh)
 
 
-class PackedShardedBatch:
-    """Host-packed kernel arrays awaiting a mesh dispatch.
-
-    The pack half (CPU: decompress limbs, radix-split words, pad to the
-    bucket) and the dispatch half (device: the sharded verify executable)
-    are split so a pipelined caller — the sidecar's depth-2 executor — can
-    pack batch N+1 on the host while batch N runs on the mesh."""
-
-    __slots__ = ("n", "good", "arrays", "fn", "bucket", "n_devices")
-
-    def __init__(self, n, good, arrays, fn, bucket, n_devices):
-        self.n = n                  # total lanes requested (incl. malformed)
-        self.good = good            # indices packed into the arrays
-        self.arrays = arrays        # four (8, bucket) uint32 word arrays
-        self.fn = fn                # jit(shard_map) executable, mesh-bound
-        self.bucket = bucket        # padded lane count actually dispatched
-        self.n_devices = n_devices
-
-    @property
-    def pad_lanes(self) -> int:
-        """Lanes dispatched that carry no real signature (bucket ladder
-        round-up + pad_to_devices) — the waste the stats attribute."""
-        return self.bucket - len(self.good)
+def pack(jobs, mesh: Mesh) -> "ed25519_jax.Packed | None":
+    """ed25519_jax.pack on the mesh's bucket rule: the single chip's ladder
+    rounded up to a multiple of the mesh size, so every device gets an
+    equal slice. The handle records the mesh width."""
+    ndev = int(mesh.devices.size)
+    packed = ed25519_jax.pack(
+        jobs, lambda n: pad_to_devices(pick_bucket(n), ndev))
+    return None if packed is None else packed._replace(n_devices=ndev)
 
 
-def pack_batch_sharded(pubkeys, msgs, sigs,
-                       mesh: Mesh) -> "PackedShardedBatch | None":
-    """Host half of the sharded verify: filter malformed lanes, pick the
-    bucket (rounded to a multiple of the mesh size so every device gets an
-    equal slice), and columnar-pack the kernel arrays. Returns None when no
-    lane is well-formed (the caller answers all-False without a dispatch).
-
-    The returned executable is the cached jit(shard_map) for this mesh —
-    in/out shardings are fixed by _IN_SPECS/_OUT_SPEC, so repeated
-    dispatches at the same bucket reuse one executable and never
-    re-partition."""
-    n = len(sigs)
-    with _obs.span("verify.prepare"):
-        good = [i for i in range(n)
-                if len(bytes(pubkeys[i])) == 32 and len(bytes(sigs[i])) == 64]
-        if not good:
-            return None
-        ndev = mesh.devices.size
-        bucket = pad_to_devices(ed25519_jax.pick_bucket(len(good)), ndev)
-        gp = [pubkeys[i] for i in good]
-        gm = [msgs[i] for i in good]
-        gs = [sigs[i] for i in good]
-        hashed = ed25519_jax.device_hash_eligible(gm)
-    with _obs.span("verify.pack"):
-        if hashed:
-            arrays, _ = ed25519_jax.precompute_batch_device(gp, gm, gs,
-                                                            bucket=bucket)
-            fn = sharded_verify_hashed_fn(mesh)
-        else:
-            arrays, _ = ed25519_jax.precompute_batch(gp, gm, gs,
-                                                     bucket=bucket)
-            fn = sharded_verify_fn(mesh)
-    return PackedShardedBatch(n, good, arrays, fn, bucket, ndev)
-
-
-def dispatch_packed(packed: PackedShardedBatch) -> np.ndarray:
-    """Device half: run the mesh executable and scatter lane results back
-    to the caller's index space (padded lanes verify False and are never
-    visible — bool[packed.n] covers exactly the requested lanes)."""
-    with _obs.span("verify.dispatch", lanes=len(packed.good),
-                   bucket=packed.bucket):
-        pending = packed.fn(*packed.arrays)
-    with _obs.span("verify.readback"):
-        out = np.asarray(pending)
-    with _obs.span("verify.scatter"):
-        ok = np.zeros(packed.n, bool)
-        for j, i in enumerate(packed.good):
-            ok[i] = out[j]
-    return ok
-
-
-def verify_batch_sharded(pubkeys, msgs, sigs, mesh: Mesh) -> np.ndarray:
-    """End-to-end sharded verify: bool[len(sigs)], malformed inputs reject.
-
-    Host packing and path dispatch are shared with the single-chip tier:
-    all-32-byte messages (tx ids) hash on device; the bucket is rounded up to
-    a multiple of the mesh size so every device gets an equal slice.
-    """
-    packed = pack_batch_sharded(pubkeys, msgs, sigs, mesh)
-    if packed is None:
-        return np.zeros(len(sigs), bool)
-    return dispatch_packed(packed)
+def graphs(mesh: Mesh) -> ed25519_jax.Graphs:
+    """The mesh's graphs for ed25519_jax.run. They verify every lane (the
+    XLA graph), so the live count is ignored."""
+    hashed = sharded_verify_hashed_fn(mesh)
+    host_hashed = sharded_verify_fn(mesh)
+    return ed25519_jax.Graphs(
+        lambda a, r, s, m, live: hashed(a, r, s, m),
+        lambda a, r, s, h, live: host_hashed(a, r, s, h), pallas=False)

@@ -174,8 +174,8 @@ def test_shadow_sampling_detects_kernel_divergence(monkeypatch):
     assert ok.tolist() == [True]
 
     # Sabotage the kernel: flip every verdict. Shadow sampling must catch it.
-    real = ed25519_jax.verify_batch
-    monkeypatch.setattr(ed25519_jax, "verify_batch",
+    real = ed25519_jax.verify_jobs
+    monkeypatch.setattr(ed25519_jax, "verify_jobs",
                         lambda *a, **k: ~real(*a, **k))
     with pytest.raises(RuntimeError, match="divergence"):
         JaxVerifier(shadow_rate=1.0, device_min_sigs=0).verify_batch(jobs)

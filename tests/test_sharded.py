@@ -11,7 +11,8 @@ import pytest
 import jax
 
 from corda_tpu.crypto import ref_ed25519 as ref
-from corda_tpu.ops import sharded
+from corda_tpu.crypto.provider import VerifyJob
+from corda_tpu.ops import ed25519_jax, sharded
 
 
 pytestmark = pytest.mark.skipif(
@@ -39,10 +40,18 @@ def _sig_fixture(n):
     return pks, msgs, sigs
 
 
+def _verify_on_mesh(pks, msgs, sigs, mesh):
+    """The mesh tier's two halves: pack on its bucket rule, run its
+    graphs."""
+    jobs = [VerifyJob(p, m, s) for p, m, s in zip(pks, msgs, sigs)]
+    return ed25519_jax.run(sharded.pack(jobs, mesh), sharded.graphs(mesh),
+                           len(jobs))
+
+
 def test_sharded_verify_matches_oracle():
     mesh = sharded.make_mesh(8)
     pks, msgs, sigs = _sig_fixture(19)  # ragged: exercises pad-and-mask
-    got = sharded.verify_batch_sharded(pks, msgs, sigs, mesh)
+    got = _verify_on_mesh(pks, msgs, sigs, mesh)
     want = np.array([ref.verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)])
     assert got.tolist() == want.tolist()
     assert want.sum() not in (0, len(want))  # fixture mixes accept and reject
@@ -53,15 +62,8 @@ def test_sharded_rejects_malformed_without_raising():
     pks, msgs, sigs = _sig_fixture(4)
     pks[1] = b"\x01" * 7        # wrong-length key
     sigs[2] = b"\x02" * 11      # wrong-length sig
-    got = sharded.verify_batch_sharded(pks, msgs, sigs, mesh)
+    got = _verify_on_mesh(pks, msgs, sigs, mesh)
     assert got[0] and not got[1] and not got[2]
-
-
-def test_pad_to_devices():
-    assert sharded.pad_to_devices(1, 8) == 8
-    assert sharded.pad_to_devices(8, 8) == 8
-    assert sharded.pad_to_devices(9, 8) == 16
-    assert sharded.pad_to_devices(0, 8) == 8
 
 
 def test_sharded_device_hash_path_matches_oracle():
@@ -74,7 +76,7 @@ def test_sharded_device_hash_path_matches_oracle():
     sigs32 = [ref.sign(bytes([(i % 255) + 1]) * 32, msgs32[i])
               if ref.verify(pks[i], msgs[i], sigs[i]) else sigs[i]
               for i in range(len(sigs))]
-    got = sharded.verify_batch_sharded(pks, msgs32, sigs32, mesh)
+    got = _verify_on_mesh(pks, msgs32, sigs32, mesh)
     want = [ref.verify(p, m, s) for p, m, s in zip(pks, msgs32, sigs32)]
     assert got.tolist() == want
     assert any(want) and not all(want)

@@ -83,10 +83,15 @@ def test_wire_roundtrip_variable_length_messages():
 
 
 def test_bucket_ladder_matches_kernel():
-    assert sc.bucket_for(1) == 64
-    assert sc.bucket_for(80) == 256
-    assert sc.bucket_for(4096) == 4096
-    assert sc.bucket_for(10 ** 9) == 65536
+    # The sidecar keys its stats by the device tier's own rule.
+    from corda_tpu.ops import buckets
+
+    assert sc.pick_bucket is buckets.pick_bucket
+    assert sc.pick_bucket(1) == 64
+    assert sc.pick_bucket(80) == 256
+    assert sc.pick_bucket(4096) == 4096
+    # Past the top rung, the bucket the device call really takes.
+    assert sc.pick_bucket(65537) == 131072
 
 
 # ---------------------------------------------------------------------------
@@ -214,7 +219,7 @@ def test_oversized_request_verifies_in_bucket_slices(sock_path):
         assert sizes == [64, 56]
         assert stats["batch_sigs_hist"] == {"64": 2}
         on_device = [64]  # the 56-signature slice took the host tier
-        lanes = sum(sc.bucket_for(k) for k in on_device)
+        lanes = sum(sc.pick_bucket(k) for k in on_device)
         assert stats["device_lanes"] == lanes
         assert stats["pad_lanes"] == lanes - sum(on_device)
         assert sum(stats["per_device_batch_sigs_hist"].values()) == len(

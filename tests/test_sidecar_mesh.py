@@ -22,6 +22,7 @@ from corda_tpu.crypto.keys import KeyPair
 from corda_tpu.crypto.provider import CpuVerifier, MeshVerifier, VerifyJob
 from corda_tpu.crypto.sidecar import SidecarServer
 from corda_tpu.node.verify_client import SidecarVerifier, fetch_sidecar_stats
+from corda_tpu.ops import buckets
 
 needs_mesh = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -188,18 +189,14 @@ def test_devices_one_keeps_single_device_verifier():
     assert make("cpu", 8).name == "cpu-openssl"
 
 
-def test_pad_to_devices_arithmetic():
-    assert sc.pad_to_devices(19, 8) == 24
-    assert sc.pad_to_devices(64, 8) == 64
-    assert sc.pad_to_devices(1, 8) == 8
-    assert sc.pad_to_devices(0, 8) == 8
-    assert sc.pad_to_devices(65, 8) == 72
-    assert sc.pad_to_devices(100, 1) == 100
-    # Every kernel bucket is already a multiple of 1/2/4/8: mesh padding
-    # beyond the bucket ladder is zero for power-of-two meshes.
-    for b in sc.BUCKETS:
-        for ndev in (1, 2, 4, 8):
-            assert sc.pad_to_devices(b, ndev) == b
+@pytest.mark.parametrize("n, n_devices, want", [
+    (19, 8, 24), (64, 8, 64), (1, 8, 8), (0, 8, 8), (65, 8, 72),
+    (100, 1, 100), (8, 8, 8), (9, 8, 16),
+    # Every rung of the ladder is already a multiple of 1, 2, 4 and 8:
+    # power-of-two meshes pad nothing beyond the ladder.
+    *[(b, ndev, b) for b in buckets.LADDER for ndev in (1, 2, 4, 8)]])
+def test_pad_to_devices_arithmetic(n, n_devices, want):
+    assert buckets.pad_to_devices(n, n_devices) == want
 
 
 # ---------------------------------------------------------------------------

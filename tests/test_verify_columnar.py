@@ -129,10 +129,10 @@ def test_mixed_schemes_split_and_keep_order(native):
 
 def test_empty_and_all_malformed_batches_dispatch_nothing(native,
                                                           monkeypatch):
-    def no_dispatch(*a, **k):
+    def no_graph(*a, **k):
         raise AssertionError("a batch with no well-formed lane dispatched")
 
-    monkeypatch.setattr(ed25519_jax, "_dispatch", no_dispatch)
+    monkeypatch.setattr(ed25519_jax, "verify_arrays", no_graph)
     v = _device_verifier()
     assert v.verify_batch([]).tolist() == []
     j = _signed(3)
